@@ -137,11 +137,11 @@ _NARROW = ("float32", "bfloat16", "float16")
 
 
 def _subjaxprs(value):
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

@@ -27,7 +27,6 @@ meshes and vice versa.
 from __future__ import annotations
 
 import os
-from typing import Any
 
 import jax
 import numpy as np
@@ -226,21 +225,14 @@ def train_state_shardings(ctx, logical, params, gstate) -> dict:
     local -> host -> prod all route through the same logical rules."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from repro.sharding.rules import shardings_for
+    from repro.sharding.rules import mirror_params, shardings_for
 
     if ctx.mesh is None:
         raise ValueError("train_state_shardings needs a distributed ShardCtx "
                          "(ctx.mesh is None); restore with shardings=None instead")
     pshard = shardings_for(logical, params, ctx.mesh, ctx.rules)
     repl = NamedSharding(ctx.mesh, PartitionSpec())
-    ptree = jax.tree.structure(params)
-
-    def mirror(sub: Any):
-        if jax.tree.structure(sub) == ptree:
-            return pshard
-        if isinstance(sub, dict):
-            return {k: mirror(v) for k, v in sub.items()}
-        return jax.tree.map(lambda _: repl, sub)
+    mirror = lambda sub: mirror_params(sub, params, pshard, repl)
 
     gshard = gstate._replace(
         step=repl,

@@ -32,7 +32,7 @@ Two grant disciplines share this apply path:
 Thread safety: one lock/condition serializes applies (the parameter server
 is sequential by definition — the asynchrony lives between processes).
 Strategy hooks trace tiny (rho, P, k) arrays; they run eagerly under a scoped
-enable_x64 so float64 parity survives the jnp round-trip.
+`jax.enable_x64` so float64 parity survives the jnp round-trip.
 """
 from __future__ import annotations
 
@@ -155,18 +155,18 @@ class ParameterStore:
     # ------------------------------------------------------------- numerics
 
     def _hook_score(self, d_own, d_avg, prev_avg):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64():
             return float(self.strategy.sim_score(
                 jnp.float64(d_own), jnp.float64(d_avg), jnp.float64(prev_avg)))
 
     def _hook_replay(self, W2, lr):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64():
             return np.asarray(self.strategy.sim_replay(
                 jnp.asarray(W2), jnp.asarray(self.wscore),
                 jnp.asarray(self.wgrads), jnp.float64(lr)))
@@ -174,11 +174,11 @@ class ParameterStore:
     def _compensate(self, g, w_fetch):
         """Non-fused compensation (e.g. gap_aware) via the mesh hook, exactly
         as the scan body does for strategies without a kernel lambda."""
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
         from repro.engine.strategies import sim_shim_state
 
-        with enable_x64():
+        with jax.enable_x64():
             shim = sim_shim_state(self.version, jnp.asarray(w_fetch),
                                   jnp.float64(self.prev_avg), self.spec.rho)
             return np.asarray(self.strategy.compensate_grads(

@@ -26,7 +26,7 @@ it with three orthogonal pieces:
 
 Parity: with the default topologies the scan trajectory reproduces train_ps
 to float64 round-off, locked in by tests/test_delaysim.py (the numpy loop
-stays as the reference). Everything runs in float64 via a scoped enable_x64
+stays as the reference). Everything runs in float64 via a scoped jax.enable_x64
 (f32 on TPU, where x64 is unsupported — parity is a CPU/GPU property).
 """
 from __future__ import annotations
@@ -38,7 +38,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.parameter_server import (  # noqa: F401  (DelaySchedule re-export)
     DelaySchedule,
@@ -59,7 +58,7 @@ def _x64():
     """Scoped float64: the paper-scale sim matches the numpy reference to
     round-off. TPUs have no f64 — there the scan runs in f32 (no parity
     guarantee, same algorithm)."""
-    return enable_x64() if jax.default_backend() != "tpu" else nullcontext()
+    return jax.enable_x64() if jax.default_backend() != "tpu" else nullcontext()
 
 
 # ------------------------------------------------------- model math (jax)

@@ -87,6 +87,43 @@ def _microbatches(batch, n_micro: int, c: int):
     return jax.tree.map(one, batch)
 
 
+def _fused_apply(fused, name: str, lam: float, cfg, ctx: ShardCtx):
+    """`apply(params, grads, w_ref, opt_state, lr) -> (params, opt_state)`:
+    the fused whole-update over the parameter tree. On a distributed mesh a
+    Pallas kernel has no partitioning rule, so the update runs under
+    `shard_map` on each device's own shard of every leaf — the leaves keep
+    the logical-rule sharding of the params (FSDP over `data`), and the
+    elementwise update needs no collective."""
+    from repro.kernels.guided_update.ops import tree_fused_update
+
+    def apply(params, grads, w_ref, opt_state, lr):
+        return tree_fused_update(fused, name, params, grads, w_ref, opt_state,
+                                 lr, lam)
+
+    if not ctx.distributed:
+        return apply
+
+    from jax.sharding import PartitionSpec as P
+
+    from repro.models.module import logical_tree
+    from repro.sharding.rules import mirror_params, shardings_for
+
+    logical = logical_tree(jax.eval_shape(lambda: T.model_init(jax.random.PRNGKey(0), cfg)))
+
+    def sharded(params, grads, w_ref, opt_state, lr):
+        specs = jax.tree.map(lambda s: s.spec,
+                             shardings_for(logical, params, ctx.mesh, ctx.rules))
+        opt_specs = mirror_params(opt_state, params, specs, P())
+        return jax.shard_map(
+            apply, mesh=ctx.mesh,
+            in_specs=(specs, specs, specs, opt_specs, P()),
+            out_specs=(specs, opt_specs),
+            check_vma=False,
+        )(params, grads, w_ref, opt_state, lr)
+
+    return sharded
+
+
 def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, lr_schedule,
                      n_micro: int = 1, n_workers: int = 0, strategy=None):
     """Returns train_step(params, gstate, batch) -> (params, gstate, metrics).
@@ -117,7 +154,7 @@ def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, l
             fused = strategy.sim_kernel(opt.name, **hy)
             fused_lam = float(strategy.sim_kernel_lambda())
     if fused is not None:
-        from repro.kernels.guided_update.ops import tree_fused_update
+        fused_apply = _fused_apply(fused, opt.name, fused_lam, cfg, ctx)
 
     def loss_fn(p, batch, corr_w):
         per_ex, aux, _ = T.forward_train(p, batch, cfg, ctx)
@@ -175,9 +212,8 @@ def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, l
             # (identity for non-dc strategies: lam == 0); w_stale only matters
             # when lam != 0, which implies gcfg.needs_stale
             w_ref = gstate.w_stale if gcfg.needs_stale else params
-            params, opt_state = tree_fused_update(
-                fused, opt.name, params, grads, w_ref, gstate.opt_state,
-                lr_eff, fused_lam)
+            params, opt_state = fused_apply(params, grads, w_ref,
+                                            gstate.opt_state, lr_eff)
         else:
             grads = strategy.compensate_grads(grads, params, gstate)
             updates, opt_state = opt.update(grads, gstate.opt_state, params, lr_eff)

@@ -136,6 +136,38 @@ def synthetic_stream(spec: ExperimentSpec, cfg, c: int):
                                 seed=spec.seed, n_corpora=c)
 
 
+def build_dispatch(spec: ExperimentSpec, strategy, cfg, ctx, c: int,
+                   n_steps: int):
+    """The jitted program `fit` dispatches: the strategy-driven train step
+    (sentinel-guarded when `spec.sentinel`), fused `spec.chunk_steps` steps
+    per call when that is > 1, with the `(params, gstate)` carry donated.
+    Lowering it on the train state's shapes compiles the step without running
+    it (`chip_smoke.py` reads the compiled text and memory from there)."""
+    import jax
+
+    from repro.engine import mesh as M
+    from repro.optim import for_run, get_optimizer
+
+    # schedule phases partition n_steps (for_run); the wsd endpoint
+    # actually reaches final_frac before the run ends
+    lr = for_run(spec.schedule, spec.lr, spec.warmup, n_steps)
+    step_fn = M.build_train_step(cfg, spec.to_guided_config(),
+                                 get_optimizer(spec.optimizer), ctx, lr,
+                                 n_micro=spec.micro, n_workers=c,
+                                 strategy=strategy)
+    if spec.sentinel:
+        # divergence sentinel (DESIGN.md §14): screen every step ON DEVICE —
+        # a rejected step keeps the previous (params, gstate) carry, so one
+        # NaN batch costs a step of progress, never the run; the scan/jit
+        # fusion is unchanged because the guard is part of step_fn itself
+        from repro.resilience import wrap_step_sentinel
+
+        step_fn = wrap_step_sentinel(step_fn, spec.sentinel,
+                                     spec.sentinel_factor)
+    return jax.jit(build_chunk_step(step_fn) if spec.chunk_steps > 1 else step_fn,
+                   donate_argnums=(0, 1))
+
+
 def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
         on_step: Optional[Callable] = None, keep_history: bool = True,
         resume: bool = False):
@@ -160,17 +192,13 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
     from repro.data.prefetch import ChunkPrefetcher, batch_put, stack_blocks
     from repro.engine import mesh as M
     from repro.engine.trainer import Report
-    from repro.optim import for_run, get_optimizer
+    from repro.optim import get_optimizer
 
     n_steps = steps or spec.steps
     cfg = spec.model_config()
     ctx = M.build_ctx(spec.mesh)
     gcfg = spec.to_guided_config()
     opt = get_optimizer(spec.optimizer)
-    # schedule phases partition n_steps (for_run); the wsd endpoint
-    # actually reaches final_frac before the run ends
-    lr = for_run(spec.schedule, spec.lr, spec.warmup, n_steps)
-
     c = spec.workers or max(ctx.n_workers, 1)
     if spec.global_batch % c != 0:
         # a real exception, not an assert (asserts vanish under python -O):
@@ -185,20 +213,14 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
     params, logical, gstate = M.init_train_state(
         key, cfg, gcfg, opt, n_workers=c, strategy=strategy
     )
-    step_fn = M.build_train_step(cfg, gcfg, opt, ctx, lr, n_micro=spec.micro,
-                                 n_workers=c, strategy=strategy)
-    if spec.sentinel:
-        # divergence sentinel (DESIGN.md §14): screen every step ON DEVICE —
-        # a rejected step keeps the previous (params, gstate) carry, so one
-        # NaN batch costs a step of progress, never the run; the scan/jit
-        # fusion is unchanged because the guard is part of step_fn itself
-        from repro.resilience import wrap_step_sentinel
-
-        step_fn = wrap_step_sentinel(step_fn, spec.sentinel,
-                                     spec.sentinel_factor)
+    if ctx.distributed:
+        # start the state where the step keeps it (params and their mirrors
+        # sharded by the logical rules), so every dispatch runs one program
+        sh = C.train_state_shardings(ctx, logical, params, gstate)
+        params, gstate = jax.device_put((params, gstate),
+                                        (sh["params"], sh["gstate"]))
+    dispatch = build_dispatch(spec, strategy, cfg, ctx, c, n_steps)
     chunked = spec.chunk_steps > 1
-    dispatch = jax.jit(build_chunk_step(step_fn) if chunked else step_fn,
-                       donate_argnums=(0, 1))
 
     start_step = 0
     if resume:

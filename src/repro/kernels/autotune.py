@@ -1,20 +1,26 @@
-"""Block-size autotuning for the flat elementwise Pallas kernels.
+"""Block-size autotuning for the elementwise Pallas kernels.
 
-Every `*_raw` wrapper in `kernels/guided_update` tiles its arrays into flat
-1-D blocks. The historical default (64k elements = 512 KiB fp32) is a good
-middle of the road, but the sweet spot depends on the backend (VMEM budget on
-TPU, occupancy on GPU) and the dtype (f64 doubles the footprint per element).
-This module measures the candidate blocks once per (kernel, dtype) on the
-current backend+device and persists the winner, so the `block=None` default of
-every `*_raw` entry point resolves to the tuned value:
+Every `*_raw` wrapper in `kernels/guided_update` tiles its arrays into blocks
+of `block` elements (`repro.kernels._tile_grid`). The historical default (64k
+elements) is a good middle of the road, but the sweet spot depends on the
+backend and the dtype. This module measures the candidate blocks once per
+(kernel, dtype) on the current backend+device and persists the winner, so the
+`block=None` default of every `*_raw` entry point resolves to the tuned value:
 
-  * **Sweep on first use** — `tuned_block(kernel, dtype)` times each candidate
-    in `CANDIDATES` on synthetic data (compiled, `block_until_ready`) and
-    caches the fastest.
+  * **Only blocks that fit** — `candidates(kernel, dtype)` keeps the entries
+    of `CANDIDATES` whose double-buffered in/out blocks fit
+    `VMEM_STREAM_BYTES` of the chip's scoped fast memory, from the kernel's
+    array count and dtypes (`_STREAMS`). A larger block is refused by the TPU
+    compiler, so the sweep never tries one (tests/test_tpu_compile.py compiles
+    every allowed block for a v5e).
+  * **Sweep on first use** — `tuned_block(kernel, dtype)` times each
+    candidate on synthetic data (compiled, `block_until_ready`) and caches the
+    fastest.
   * **Persistent JSON cache keyed by backend+device** — winners land in
-    `<cache_dir>/<backend>-<device_kind>.json` (`REPRO_AUTOTUNE_CACHE`
-    overrides the directory; CI caches it next to the XLA compilation cache),
-    so repeat runs — and repeat *processes* — skip the sweep entirely.
+    `<cache_dir>/<backend>-<device_kind>.json`: `REPRO_AUTOTUNE_CACHE` when
+    set, else `.cache/autotune` in the checkout
+    (`repro.common.cache.checkout_cache`), so repeat runs — and repeat
+    *processes* — skip the sweep entirely.
   * **Interpret backends skip the sweep.** On CPU the kernels run in Pallas
     interpret mode (pure emulation, see `default_interpret`): its wall time
     says nothing about the compiled kernel, so the default block is returned
@@ -33,14 +39,33 @@ import re
 import tempfile
 import time
 
-#: candidate flat-block sizes (elements): 16k .. 256k
-CANDIDATES = (16384, 32768, 65536, 131072, 262144)
+#: candidate block sizes (elements): 32k (the smallest full `_tile_grid`
+#: block, ROW_BLOCK x LANE_BLOCK) .. 512k
+CANDIDATES = (32768, 65536, 131072, 262144, 524288)
 
-#: the pre-autotune default (and the interpret-mode fallback)
+#: the pre-autotune default (and the interpret-mode fallback); fits every
+#: kernel of the family at every dtype
 DEFAULT_BLOCK = 65536
 
-#: elements per timing probe — large enough that every candidate runs a
-#: multi-step grid (1M = 4..64 grid steps across CANDIDATES)
+#: fast memory the double-buffered in/out blocks of one kernel may take.
+#: Compiling for a v5e accepts every block of the family whose streams come
+#: to 12 MiB and refuses every one at 16 MiB: the kernel body's f32
+#: temporaries need room beside the streams.
+VMEM_STREAM_BYTES = 12 << 20
+
+#: per kernel: (arrays in the weight dtype, arrays in the compute dtype)
+#: summed over inputs and outputs — w, g, w_stale in and w out, plus each
+#: accumulator in and out
+_STREAMS = {
+    "guided_sgd_update": (4, 0),
+    "guided_momentum_update": (4, 2),
+    "guided_rmsprop_update": (4, 2),
+    "guided_adam_update": (4, 4),
+}
+
+#: elements per timing probe, as a (rows, LANE_BLOCK) matrix so that each
+#: candidate tiles it into blocks of its own size — large enough that every
+#: candidate runs a multi-step grid (1M = 2..32 grid steps across CANDIDATES)
 _PROBE_N = 1 << 20
 _PROBE_ITERS = 3
 
@@ -50,10 +75,26 @@ _MEMO: dict = {}
 
 
 def cache_dir() -> str:
-    return os.environ.get(
-        "REPRO_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune"),
-    )
+    from repro.common.cache import checkout_cache
+
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or checkout_cache("autotune")
+
+
+def candidates(kernel: str, dtype) -> tuple:
+    """The entries of CANDIDATES whose double-buffered blocks fit
+    VMEM_STREAM_BYTES for `kernel` on weights of `dtype`."""
+    import jax.numpy as jnp
+
+    try:
+        n_w, n_acc = _STREAMS[kernel]
+    except KeyError:
+        raise KeyError(
+            f"no stream table for kernel {kernel!r}; known: {', '.join(_STREAMS)}"
+        ) from None
+    w = jnp.dtype(dtype).itemsize
+    acc = jnp.promote_types(dtype, jnp.float32).itemsize
+    per_elem = 2 * (n_w * w + n_acc * acc)  # x2: Pallas double-buffers
+    return tuple(b for b in CANDIDATES if b * per_elem <= VMEM_STREAM_BYTES)
 
 
 def _device_tag() -> str:
@@ -103,15 +144,18 @@ def clear_memo() -> None:
 
 def _default_measure(kernel: str, dtype, block: int) -> float:
     """Wall seconds per call of `kernel` at `block` on synthetic _PROBE_N-
-    element data (compiled path; the first call pays the jit and is excluded)."""
+    element data (compiled path; the first call pays the jit and is excluded).
+    A block the compiler refuses raises: `candidates` admits none."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.kernels import LANE_BLOCK
     from repro.kernels.guided_update import kernel as K
 
     rng = np.random.default_rng(0)
-    w = jnp.asarray(rng.standard_normal(_PROBE_N), dtype)
+    w = jnp.asarray(
+        rng.standard_normal((_PROBE_N // LANE_BLOCK, LANE_BLOCK)), dtype)
     g = w * 0.01
     ws = w + 0.05
     acc = jnp.abs(w) * 0.1
@@ -180,8 +224,15 @@ def tuned_block(kernel: str, dtype, *, dirname: str = None, measure=None) -> int
         # no persist: a later run on a kernel-capable backend should sweep
         return DEFAULT_BLOCK
 
+    from concurrent.futures import ThreadPoolExecutor
+
     probe = measure or _default_measure
-    timings = {b: probe(kernel, dtype, b) for b in CANDIDATES}
+    # the first resolution usually happens while a train step is being
+    # traced, and JAX's trace state is per thread: probing on a fresh thread
+    # runs the candidates on the device instead of staging them into the step
+    with ThreadPoolExecutor(1) as pool:
+        timings = pool.submit(lambda: {
+            b: probe(kernel, dtype, b) for b in candidates(kernel, dtype)}).result()
     block = min(timings, key=timings.get)
     data[key] = block
     _store(path, data)

@@ -35,11 +35,13 @@ weights (promote_types(w.dtype, float32)), so the float64 parity runs of the
 scan backend reproduce the numpy reference loop exactly while bf16/f32 mesh
 weights keep the f32 arithmetic the TPU path compiles to.
 
-Tiling: flat 1-D blocks via `repro.kernels._flat_grid`. `block=None` (the
-default) resolves through `repro.kernels.autotune.tuned_block` — a per
-(kernel, dtype, backend+device) measured winner, falling back to 64k elements
-(512 KiB fp32) where sweeping is meaningless. Resolution happens at trace
-time, so the tuned block is a static of the enclosing jit.
+Tiling: 2-D `(rows, cols)` blocks over each leaf's own layout via
+`repro.kernels._tile_grid`. `block` is the element count of one block;
+`block=None` (the default) resolves through `repro.kernels.autotune.tuned_block`
+— a per (kernel, dtype, backend+device) measured winner among the blocks that
+fit the chip's fast memory, falling back to 64k elements where sweeping is
+meaningless. Resolution happens at trace time, so the tuned block is a static
+of the enclosing jit.
 """
 from __future__ import annotations
 
@@ -48,8 +50,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _flat_grid, default_interpret  # noqa: F401  (re-export: ops.py, delaysim)
+from repro.kernels import _tile_grid, default_interpret  # noqa: F401  (re-export: ops.py, delaysim)
 from repro.kernels.autotune import tuned_block
 
 
@@ -65,19 +68,22 @@ def _resolve(block, interpret, kernel_name, dtype):
     return block, interpret
 
 
-def _launch(kernel_fn, flats, scalars, block, grid, out_dtypes, interpret):
-    """One flat elementwise pallas_call: every array in/out tiled `(block,)`,
-    the scalar pack riding along whole in ANY memory space."""
-    m = flats[0].shape[0]
-    bspec = lambda: pl.BlockSpec((block,), lambda i: (i,))
+def _launch(kernel_fn, views, scalars, block_shape, grid, out_dtypes,
+            interpret):
+    """One elementwise pallas_call over `_tile_grid` views: every array in/out
+    tiled `block_shape`, the scalar pack riding along whole in SMEM (Mosaic
+    loads scalars only from SMEM or VMEM; an ANY-space ref would need an
+    explicit DMA)."""
+    shape = views[0].shape
+    bspec = lambda: pl.BlockSpec(block_shape, lambda i, j: (i, j))
     return pl.pallas_call(
         kernel_fn,
-        grid=(grid,),
-        in_specs=[bspec() for _ in flats] + [pl.BlockSpec(memory_space=pl.ANY)],
+        grid=grid,
+        in_specs=[bspec() for _ in views] + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bspec() for _ in out_dtypes],
-        out_shape=[jax.ShapeDtypeStruct((m,), d) for d in out_dtypes],
+        out_shape=[jax.ShapeDtypeStruct(shape, d) for d in out_dtypes],
         interpret=interpret,
-    )(*flats, scalars)
+    )(*views, scalars)
 
 
 def _sgd_kernel(w_ref, g_ref, ws_ref, scal_ref, out_ref):
@@ -155,14 +161,13 @@ def _adam_kernel(w_ref, g_ref, ws_ref, m_ref, v_ref, scal_ref, out_ref,
 
 def guided_sgd_update_raw(w, g, w_stale, lr, lam, *, block: int = None,
                           interpret: bool = None):
-    """Flat fused update for one parameter leaf. Returns new w."""
+    """Fused update for one parameter leaf. Returns new w."""
     block, interpret = _resolve(block, interpret, "guided_sgd_update", w.dtype)
     ct = _compute_dtype(w.dtype)
     scalars = jnp.stack([jnp.asarray(lr, ct), jnp.asarray(lam, ct)])
-    flats, block, grid, n = _flat_grid(block, w, g, w_stale)
-    (out,) = _launch(_sgd_kernel, flats, scalars, block, grid,
-                     [w.dtype], interpret)
-    return out[:n].reshape(w.shape)
+    views, bs, grid = _tile_grid(block, w, g, w_stale)
+    (out,) = _launch(_sgd_kernel, views, scalars, bs, grid, [w.dtype], interpret)
+    return out.reshape(w.shape)
 
 
 def guided_momentum_update_raw(w, g, w_stale, m, lr, lam, beta, *,
@@ -175,10 +180,10 @@ def guided_momentum_update_raw(w, g, w_stale, m, lr, lam, beta, *,
     scalars = jnp.stack([
         jnp.asarray(lr, ct), jnp.asarray(lam, ct), jnp.asarray(beta, ct),
     ])
-    flats, block, grid, n = _flat_grid(block, w, g, w_stale, m)
-    out, m_new = _launch(partial(_momentum_kernel, nesterov), flats, scalars,
-                         block, grid, [w.dtype, ct], interpret)
-    return out[:n].reshape(w.shape), m_new[:n].reshape(w.shape)
+    views, bs, grid = _tile_grid(block, w, g, w_stale, m)
+    out, m_new = _launch(partial(_momentum_kernel, nesterov), views, scalars,
+                         bs, grid, [w.dtype, ct], interpret)
+    return out.reshape(w.shape), m_new.reshape(w.shape)
 
 
 def guided_rmsprop_update_raw(w, g, w_stale, r, lr, lam, beta, eps, *,
@@ -190,10 +195,10 @@ def guided_rmsprop_update_raw(w, g, w_stale, r, lr, lam, beta, eps, *,
         jnp.asarray(lr, ct), jnp.asarray(lam, ct),
         jnp.asarray(beta, ct), jnp.asarray(eps, ct),
     ])
-    flats, block, grid, n = _flat_grid(block, w, g, w_stale, r)
-    out, r_new = _launch(_rmsprop_kernel, flats, scalars, block, grid,
+    views, bs, grid = _tile_grid(block, w, g, w_stale, r)
+    out, r_new = _launch(_rmsprop_kernel, views, scalars, bs, grid,
                          [w.dtype, ct], interpret)
-    return out[:n].reshape(w.shape), r_new[:n].reshape(w.shape)
+    return out.reshape(w.shape), r_new.reshape(w.shape)
 
 
 def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
@@ -215,8 +220,7 @@ def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
         1.0 - jnp.asarray(b1, ct) ** tct, 1.0 - jnp.asarray(b2, ct) ** tct,
         jnp.asarray(eps, ct),
     ])
-    flats, block, grid, n = _flat_grid(block, w, g, w_stale, m, v)
-    out, m_new, v_new = _launch(_adam_kernel, flats, scalars, block, grid,
+    views, bs, grid = _tile_grid(block, w, g, w_stale, m, v)
+    out, m_new, v_new = _launch(_adam_kernel, views, scalars, bs, grid,
                                 [w.dtype, ct, ct], interpret)
-    return (out[:n].reshape(w.shape), m_new[:n].reshape(w.shape),
-            v_new[:n].reshape(w.shape))
+    return out.reshape(w.shape), m_new.reshape(w.shape), v_new.reshape(w.shape)

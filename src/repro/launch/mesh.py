@@ -8,7 +8,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.common.compat import make_mesh
+
+
+def make_mesh(shape, axes):
+    """Mesh over the local devices with every axis in Auto mode (the
+    sharding rules place arrays; the partitioner fills in the rest)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -23,6 +23,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.common.cache import enable_compile_cache
 from repro.configs import get_config
 from repro.engine import build_ctx  # shared mesh-kind -> ShardCtx resolution
 from repro.models import transformer as T
@@ -55,6 +56,7 @@ def main(argv=None):
                     help="checkpoint step to serve (default: latest manifest entry)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

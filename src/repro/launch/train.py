@@ -245,6 +245,9 @@ def main(argv=None):
     ap.add_argument("--dist-timeout", type=float, default=120.0,
                     help="watchdog: max seconds without store progress")
     args = ap.parse_args(argv)
+    from repro.common.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.role == "worker":
         from repro.dist.worker import main as worker_main

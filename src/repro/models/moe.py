@@ -218,9 +218,7 @@ def moe_apply(p, x, cfg, ctx, capacity_factor=None):
         p_specs["wi"] = P(batch_axes, None, None, ctx.model_axis)
         p_specs["wo"] = P(batch_axes, ctx.model_axis, None)
 
-    from repro.common.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local_psum,
         mesh=ctx.mesh,
         in_specs=(p_specs, P(*batch_spec, None, None)),

@@ -229,9 +229,7 @@ def sharded_decode_attention(q, k_cache, v_cache, cache_len, cfg, ctx: ShardCtx)
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.common.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), P(), P(axis)),

@@ -122,6 +122,23 @@ def shardings_for(logical_tree, value_tree, mesh: Mesh, rules: AxisRules):
     return jax.tree.map(one, logical_tree, value_tree, is_leaf=lambda x: isinstance(x, tuple) and all(isinstance(e, (str, type(None))) for e in x))
 
 
+def mirror_params(sub, params, per_param, other):
+    """A tree shaped like `sub` (a piece of train state) that carries
+    `per_param` — a tree shaped like `params` — wherever a subtree of `sub`
+    has the params' structure (w_stale, optimizer accumulators), and `other`
+    at every other leaf (step counters, scalars)."""
+    ptree = jax.tree.structure(params)
+
+    def go(s):
+        if jax.tree.structure(s) == ptree:
+            return per_param
+        if isinstance(s, dict):
+            return {k: go(v) for k, v in s.items()}
+        return jax.tree.map(lambda _: other, s)
+
+    return go(sub)
+
+
 @dataclasses.dataclass(frozen=True)
 class ShardCtx:
     """Runtime distribution context threaded through the model stack.

@@ -243,8 +243,7 @@ class TestX64UnscopedJnpRule:
     def test_scoped_jnp_clean(self):
         src = """
         def norm(g):
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with jax.enable_x64():
                 return jnp.linalg.norm(g)
         """
         assert rules_of(lint(src, self.PATH), "x64-unscoped-jnp") == []
@@ -368,18 +367,16 @@ class TestAssertTraces:
 
 class TestAuditDtypes:
     def test_seeded_demotion_found(self):
-        from jax.experimental import enable_x64
 
         def leaky(x):
             return jnp.sum(x.astype(jnp.float32))
 
-        with enable_x64():
+        with jax.enable_x64():
             viol = audit_dtypes(leaky, jnp.zeros(4, jnp.float64))
         assert viol and viol[0].primitive == "convert_element_type"
         assert "float64" in viol[0].in_dtypes
 
     def test_demotion_inside_scan_found(self):
-        from jax.experimental import enable_x64
 
         def loop(x):
             def body(c, _):
@@ -387,14 +384,13 @@ class TestAuditDtypes:
             c, _ = jax.lax.scan(body, x, None, length=3)
             return c
 
-        with enable_x64():
+        with jax.enable_x64():
             viol = audit_dtypes(loop, jnp.zeros(2, jnp.float64))
         assert viol and "scan" in viol[0].path
 
     def test_f64_preserving_fn_clean(self):
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64():
             viol = audit_dtypes(lambda x: jnp.sum(x * 2.0),
                                 jnp.zeros(4, jnp.float64))
         assert viol == []
@@ -402,11 +398,10 @@ class TestAuditDtypes:
     def test_guided_update_refs_preserve_f64(self):
         """The paper's update rules stay float64 end to end — the runtime
         twin of the f32-in-f64-path lint rule."""
-        from jax.experimental import enable_x64
 
         from repro.kernels.guided_update import ref as R
 
-        with enable_x64():
+        with jax.enable_x64():
             w = jnp.ones((8, 4), jnp.float64)
             g = jnp.full((8, 4), .5, jnp.float64)
             assert audit_dtypes(R.guided_sgd_update_ref,

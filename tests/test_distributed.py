@@ -27,7 +27,7 @@ SCRIPT = textwrap.dedent(
     from repro.models.module import split_params
     from repro.sharding.rules import ShardCtx, DEFAULT_RULES, LOCAL_CTX
 
-    from repro.common.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4, 2), ("data", "model"))
 
     # ---------------- MoE: local vs gather vs all-to-all ----------------
@@ -111,3 +111,50 @@ def test_distributed_semantics():
     assert "moe alltoall OK" in out.stdout
     assert "sharded decode OK" in out.stdout
     assert "distributed train step OK" in out.stdout
+
+
+HOST_MESH_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax, numpy as np
+    import repro.kernels.guided_update.ops as OPS
+    # the fused update through the Pallas kernel (interpreted on the CPU), as
+    # on a TPU, so the mesh run takes its per-shard shard_map path
+    OPS.default_interpret = lambda: False
+    from repro.engine import ExperimentSpec, Trainer
+
+    base = ExperimentSpec(backend="mesh", arch="yi_9b", reduced=True,
+                          mode="ssgd", strategy="guided_fused", rho=2,
+                          global_batch=8, seq_len=32, steps=6, chunk_steps=2,
+                          prefetch=True, lr=0.05)
+    runs = {}
+    for name, spec in (("local", base.replace(mesh="local", workers=4)),
+                       ("host", base.replace(mesh="host"))):
+        r = Trainer.from_spec(spec).fit()
+        runs[name] = r
+    host, local = runs["host"], runs["local"]
+    assert [h["corr_w"] for h in host.history] == [h["corr_w"] for h in local.history]
+    np.testing.assert_allclose([h["loss"] for h in host.history],
+                               [h["loss"] for h in local.history], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(host.model), jax.tree.leaves(local.model)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
+    specs = {str(x.sharding.spec) for x in jax.tree.leaves(host.model)}
+    assert any("data" in s for s in specs), specs   # FSDP-sharded leaves
+    print("host mesh fused update OK")
+    """
+)
+
+
+def test_host_mesh_fused_update_matches_one_device():
+    """gSSGD on a 4-device host mesh (c = 4 data shards, params FSDP-sharded,
+    the fused update kernel per shard) == c = 4 workers on one device."""
+    out = subprocess.run(
+        [sys.executable, "-c", HOST_MESH_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin")},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-3000:])
+    assert "host mesh fused update OK" in out.stdout

@@ -195,12 +195,11 @@ def test_fused_adam_matches_optimizer_composition(n, block, impl, t):
 def test_fused_kernels_match_ref_float64():
     """The f64 regime (delay-sim parity): Pallas kernel vs the pure-jnp ref
     at the scan backend's acceptance bar, odd size exercising the pad path."""
-    from jax.experimental import enable_x64
 
     from repro.kernels.guided_update import kernel as K
     from repro.kernels.guided_update import ref as R
 
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(7)
         n = 37 * 129
         w = jnp.asarray(rng.standard_normal(n), jnp.float64)
@@ -250,7 +249,10 @@ def test_autotune_cache_roundtrip(tmp_path):
     got = autotune.tuned_block("guided_adam_update", jnp.float32,
                                dirname=str(tmp_path), measure=fake_measure)
     assert got == 32768
-    assert sorted(calls) == sorted(autotune.CANDIDATES)
+    # only the blocks that fit fast memory are swept (adam f32: 64 B/element)
+    assert sorted(calls) == sorted(autotune.candidates("guided_adam_update",
+                                                       jnp.float32))
+    assert max(calls) * 64 <= autotune.VMEM_STREAM_BYTES
 
     path = autotune.cache_path(str(tmp_path))
     import json
@@ -304,3 +306,24 @@ def test_autotune_tuned_block_drives_kernel_result_identical(tmp_path):
     b = K.guided_momentum_update_raw(w, g, ws, m, 0.2, 0.04, 0.9, block=256)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_autotune_sweep_inside_a_trace_runs_the_probes(tmp_path):
+    """The first resolution happens while the train step is traced; the
+    probes must still run on the device (concrete arrays), not be staged
+    into the step being traced — else the sweep times the tracer."""
+    from repro.kernels import autotune
+    from repro.kernels.guided_update import kernel as K
+
+    seen = []
+
+    def measure(kernel, dtype, block):
+        seen.append(isinstance(jnp.ones(2) + 1, jax.core.Tracer))
+        return float(block)
+
+    autotune.clear_memo()
+    step = jax.jit(lambda w: K.guided_sgd_update_raw(
+        w, w, w, 0.1, 0.0, block=autotune.tuned_block(
+            "guided_sgd_update", w.dtype, dirname=str(tmp_path), measure=measure)))
+    step(jnp.ones((16, 256)))
+    assert seen and not any(seen)

@@ -115,7 +115,7 @@ def test_correction_weights_scale_invariance(scores, k):
 @given(st.integers(2, 64))
 @settings(max_examples=20, deadline=None)
 def test_microbatch_split_partitions_batch(c):
-    from repro.train.steps import _microbatches
+    from repro.engine.mesh import _microbatches
 
     B = c * 4
     x = jnp.arange(B * 3).reshape(B, 3)

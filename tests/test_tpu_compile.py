@@ -1,0 +1,110 @@
+"""Compile the fused guided-update kernels for a TPU v5e that is described,
+not attached: what the chip's compiler refuses fails here, at no chip time.
+
+The topology is described inside a module fixture (never at import): only one
+process may load the TPU library, and every xdist worker imports this file.
+The persistent compilation cache is off around these compiles: an entry
+written for a described chip cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from repro.kernels import autotune
+from repro.kernels.guided_update import kernel as K
+
+#: yi-9b leaves: the FFN input projection, the embedding table, a norm
+LEAVES = [(4096, 11008), (64000, 4096), (4096,)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _update(name, block):
+    """(fn, n_weight_arrays, n_accumulators) for one kernel, compiled (not
+    interpreted) at `block`."""
+    kw = dict(block=block, interpret=False)
+    if name == "sgd":
+        return lambda w, g, ws: K.guided_sgd_update_raw(w, g, ws, 0.1, 0.04, **kw), 0
+    if name == "momentum":
+        return (lambda w, g, ws, m: K.guided_momentum_update_raw(
+            w, g, ws, m, 0.1, 0.04, 0.9, **kw), 1)
+    return (lambda w, g, ws, m, v: K.guided_adam_update_raw(
+        w, g, ws, m, v, 3, 0.1, 0.04, 0.9, 0.999, 1e-8, **kw), 2)
+
+
+def _compile(name, shape, dtype, block, sharding):
+    fn, n_acc = _update(name, block)
+    acc = jnp.promote_types(dtype, jnp.float32)
+    args = ([jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * 3
+            + [jax.ShapeDtypeStruct(shape, acc, sharding=sharding)] * n_acc)
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("shape", LEAVES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
+def test_kernel_compiles_at_yi_9b_leaf(topo, one_chip, name, shape):
+    compiled = _compile(name, shape, jnp.bfloat16, autotune.DEFAULT_BLOCK, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
+def test_every_allowed_autotune_block_compiles(topo, one_chip, name, dtype):
+    """The sweep may pick any of `candidates`; the compiler must take each
+    (a refused block is an error at the first train step, not a slow run)."""
+    allowed = autotune.candidates(f"guided_{name}_update", dtype)
+    assert autotune.DEFAULT_BLOCK in allowed
+    for block in allowed:
+        _compile(name, (4096, 11008), jnp.dtype(dtype), block, one_chip)
+
+
+def test_fused_update_compiles_per_shard_on_2x2_mesh(topo, monkeypatch):
+    """On a mesh the kernel runs under shard_map on each device's shard of the
+    FSDP-sharded leaves (a Pallas call has no partitioning rule)."""
+    from repro.configs import get_config
+    from repro.engine.mesh import _fused_apply
+    from repro.kernels.guided_update.ops import fused_update_for
+    from repro.models import transformer as T
+    from repro.models.module import split_params
+    from repro.sharding.rules import DEFAULT_RULES, ShardCtx, shardings_for
+
+    # the host is a CPU: steer the wrapper onto the compiled kernel
+    monkeypatch.setattr(K, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    ctx = ShardCtx(mesh=mesh, rules=DEFAULT_RULES)
+    cfg = get_config("yi_9b").replace(n_layers=1)
+    params, logical = split_params(
+        jax.eval_shape(lambda: T.model_init(jax.random.PRNGKey(0), cfg)))
+    sh = shardings_for(logical, params, mesh, DEFAULT_RULES)
+    p = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                     params, sh)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, PartitionSpec()))
+    apply = _fused_apply(fused_update_for("sgd", impl="kernel"), "sgd", 0.04, cfg, ctx)
+    text = jax.jit(apply).lower(p, p, p, {}, lr).compile().as_text()
+    assert text.count("tpu_custom_call") == len(jax.tree.leaves(params))
+    assert "all-gather" not in text  # each kernel reads only its own shard
