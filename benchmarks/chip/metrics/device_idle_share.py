@@ -1,0 +1,8 @@
+"""device_idle_share: the share of the traced window in which no operation
+ran on the device, averaged over the chips; in percent."""
+
+
+def read(run):
+    if run.trace is None or not run.trace.ops:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s())
