@@ -17,6 +17,10 @@ jit call; this module keeps that dispatch fed. Two pieces:
 
 Both are backend-agnostic: the "blocks" are arbitrary pytrees, so the same
 prefetcher stages single per-step batches when `chunk_steps=1`.
+
+The worker's profiler spans: `prefetch.make` (drawing the next item from the
+source: batch generation and stacking; the last one finds the end of a finite
+stream) and `prefetch.put` (the device placement).
 """
 from __future__ import annotations
 
@@ -114,12 +118,17 @@ class ChunkPrefetcher:
     # ------------------------------------------------------------- worker
     def _work(self, it: Iterator) -> None:
         try:
+            from jax.profiler import TraceAnnotation
+
             while not self._stop.is_set():
                 try:
-                    item = next(it)
+                    with TraceAnnotation("prefetch.make"):
+                        item = next(it)
                 except StopIteration:
                     break
-                self._offer(self._put(item))
+                with TraceAnnotation("prefetch.put"):
+                    item = self._put(item)
+                self._offer(item)
         except BaseException as e:  # surfaced from __next__, not swallowed
             with self._lock:
                 self._err = e

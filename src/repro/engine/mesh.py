@@ -13,6 +13,13 @@ paper's technique meets the mesh here (DESIGN.md §3):
     and the consistency-score update;
   * ASGD staleness is simulated through gstate.w_stale exactly as before.
 
+Each op's phase is named in its `op_name` metadata (`jax.named_scope`; the
+compiled instructions are the same without it): `forward` (its backward
+carries `transpose(jvp(forward))`, its remat recompute
+`rematted_computation`), `loss_head` within it, `update` (compensation and
+the optimizer apply) and `guided` (correction weights, a second update,
+the consistency bookkeeping). xprof's framework-op view groups by them.
+
 Nothing here hard-codes a compensation scheme: new strategies registered in
 `repro.engine.strategies` run through this step unchanged.
 """
@@ -157,7 +164,8 @@ def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, l
         fused_apply = _fused_apply(fused, opt.name, fused_lam, cfg, ctx)
 
     def loss_fn(p, batch, corr_w):
-        per_ex, aux, _ = T.forward_train(p, batch, cfg, ctx)
+        with jax.named_scope("forward"):
+            per_ex, aux, _ = T.forward_train(p, batch, cfg, ctx)
         B = per_ex.shape[0]
         E_i = per_ex.reshape(c, B // c).mean(axis=1)
         mean_loss = E_i.mean()
@@ -192,7 +200,8 @@ def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, l
 
         def at(p, w):
             def wl(q):
-                per_ex, _, _ = T.forward_train(q, batch, cfg, ctx)
+                with jax.named_scope("forward"):
+                    per_ex, _, _ = T.forward_train(q, batch, cfg, ctx)
                 return (w * per_ex.reshape(c, -1).mean(1)).sum()
 
             return jax.grad(wl)(p)
@@ -200,35 +209,38 @@ def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, l
         return at
 
     def train_step(params, gstate: G.GuidedState, batch):
-        corr_w = strategy.correction_weights(gstate, c)
+        with jax.named_scope("guided"):
+            corr_w = strategy.correction_weights(gstate, c)
 
         grad_at = gstate.w_stale if gcfg.needs_stale else params
         grads, E_i, mean_loss = grads_and_losses(grad_at, batch, corr_w)
 
         lr = lr_schedule(gstate.step)
         lr_eff = lr * c if gcfg.mode != "seq" else lr
-        if fused is not None:
-            # compensation rides inside the fused update as the lam fold
-            # (identity for non-dc strategies: lam == 0); w_stale only matters
-            # when lam != 0, which implies gcfg.needs_stale
-            w_ref = gstate.w_stale if gcfg.needs_stale else params
-            params, opt_state = fused_apply(params, grads, w_ref,
-                                            gstate.opt_state, lr_eff)
-        else:
-            grads = strategy.compensate_grads(grads, params, gstate)
-            updates, opt_state = opt.update(grads, gstate.opt_state, params, lr_eff)
-            params = tree_add(params, updates)
-        if strategy.needs_correction:
-            # only correcting strategies trace the second weighted
-            # forward+backward; for the rest (guided_fused folds its replay
-            # into THIS backward) the closure never enters the HLO
-            params = strategy.correct(params, gstate, lr, weighted_grad_fn(batch))
+        with jax.named_scope("update"):
+            if fused is not None:
+                # compensation rides inside the fused update as the lam fold
+                # (identity for non-dc strategies: lam == 0); w_stale only
+                # matters when lam != 0, which implies gcfg.needs_stale
+                w_ref = gstate.w_stale if gcfg.needs_stale else params
+                params, opt_state = fused_apply(params, grads, w_ref,
+                                                gstate.opt_state, lr_eff)
+            else:
+                grads = strategy.compensate_grads(grads, params, gstate)
+                updates, opt_state = opt.update(grads, gstate.opt_state, params, lr_eff)
+                params = tree_add(params, updates)
+        with jax.named_scope("guided"):
+            if strategy.needs_correction:
+                # only correcting strategies trace the second weighted
+                # forward+backward; for the rest (guided_fused folds its
+                # replay into THIS backward) the closure never enters the HLO
+                params = strategy.correct(params, gstate, lr, weighted_grad_fn(batch))
 
-        gstate = G.advance(
-            gstate, gcfg, opt_state, params, E_i, mean_loss,
-            extra=strategy.update_extra(gstate, grads),
-            score=strategy.score(gstate, E_i, mean_loss),
-        )
+            gstate = G.advance(
+                gstate, gcfg, opt_state, params, E_i, mean_loss,
+                extra=strategy.update_extra(gstate, grads),
+                score=strategy.score(gstate, E_i, mean_loss),
+            )
         metrics = {
             "loss": mean_loss,
             "worker_loss_var": jnp.var(E_i),

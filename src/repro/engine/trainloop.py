@@ -35,9 +35,17 @@ Contracts (locked in tests/test_trainloop.py):
     `chunk_steps=1` restores the legacy per-step scalar contract. Either way
     the `params` handed over are donated to the next dispatch — read or save
     them synchronously inside the callback.
+
+Profiler spans (`jax.profiler`; no-ops when no trace is being recorded):
+`fit.input_wait` (taking the next staged block), `fit.dispatch` (the host
+side of one dispatch, a step span numbered by the chunk's first step),
+`fit.compile` (a chunk shape's first dispatch through its
+`block_until_ready`: the region `Report.compile_time_s` sums), `fit.on_step`
+(the callback) and `fit.checkpoint` (each snapshot's device->host copy).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, List, Optional
 
@@ -187,6 +195,7 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
     from repro import checkpoint as C
     from repro.data.prefetch import ChunkPrefetcher, batch_put, stack_blocks
@@ -304,24 +313,26 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
         for k in sizes:
             # staging always goes through batch_put: sharded H2D placement on
             # distributed meshes, plain jnp.asarray-equivalent on local
-            block = next(source) if spec.prefetch else put(next(source))
+            with TraceAnnotation("fit.input_wait"):
+                block = next(source) if spec.prefetch else put(next(source))
             # every FIRST dispatch of a chunk shape jit-compiles (the uneven
             # tail and ckpt_every-split chunks each get their own program);
             # timing those (one host sync each) is what lets Report split
             # compile time out of the warm steps/s
             is_new = k not in seen_sizes
-            if is_new:
-                if m is not None:
-                    # drain queued warm dispatches first, or their execution
-                    # lands inside the timed window and inflates compile_time
-                    jax.block_until_ready(m)
-                t_dispatch = time.perf_counter()
-            params, gstate, m = dispatch(params, gstate, block)
-            if is_new:
+            if is_new and m is not None:
+                # drain queued warm dispatches first, or their execution
+                # lands inside the timed window and inflates compile_time
                 jax.block_until_ready(m)
-                compile_time_s += time.perf_counter() - t_dispatch
-                compiled_steps += k
-                seen_sizes.add(k)
+            with (TraceAnnotation("fit.compile") if is_new else contextlib.nullcontext()):
+                t_dispatch = time.perf_counter()
+                with StepTraceAnnotation("fit.dispatch", step_num=done):
+                    params, gstate, m = dispatch(params, gstate, block)
+                if is_new:
+                    jax.block_until_ready(m)
+                    compile_time_s += time.perf_counter() - t_dispatch
+                    compiled_steps += k
+                    seen_sizes.add(k)
             done += k
             if spec.sentinel:
                 # stays device-side (async jnp add): ONE host read after the
@@ -331,11 +342,13 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
             if keep_history:
                 raw.append((done - k, k, m))
             if on_step is not None:
-                on_step(done - 1, m, params)
+                with TraceAnnotation("fit.on_step"):
+                    on_step(done - 1, m, params)
             if ckpt is not None and spec.ckpt_every and done % spec.ckpt_every == 0:
                 # device->host copy here (chunk boundary, before the next
                 # dispatch donates these buffers); serialization is async
-                ckpt.save(done, C.snapshot(params, gstate, done))
+                with TraceAnnotation("fit.checkpoint"):
+                    ckpt.save(done, C.snapshot(params, gstate, done))
             if stop["sig"] is not None:
                 break
         if m is not None:
@@ -364,7 +377,8 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
                     # final full-state snapshot (dedupes against a periodic
                     # save that already covered `done`)
                     if done > start_step or C.latest_step(spec.ckpt_dir) is None:
-                        ckpt.save(done, C.snapshot(params, gstate, done))
+                        with TraceAnnotation("fit.checkpoint"):
+                            ckpt.save(done, C.snapshot(params, gstate, done))
                 finally:
                     ckpt.close()  # drain + join even if the save failed
             except Exception:

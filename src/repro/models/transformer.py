@@ -445,10 +445,9 @@ def forward_train(params, batch, cfg, ctx: ShardCtx = LOCAL_CTX):
     B, S = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     x, aux, _ = _stack_scan(params, x, cfg, ctx, positions)
-    logits = _head(params, x, cfg)
-    labels = batch["labels"]
-    mask = batch.get("mask")
-    per_ex = L.per_example_cross_entropy(logits, labels, mask)
+    with jax.named_scope("loss_head"):
+        logits = _head(params, x, cfg)
+        per_ex = L.per_example_cross_entropy(logits, batch["labels"], batch.get("mask"))
     return per_ex, aux, logits
 
 

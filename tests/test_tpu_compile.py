@@ -7,6 +7,7 @@ The persistent compilation cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +70,11 @@ def _compile(name, shape, dtype, block, sharding):
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
 def test_kernel_compiles_at_yi_9b_leaf(topo, one_chip, name, shape):
     compiled = _compile(name, shape, jnp.bfloat16, autotune.DEFAULT_BLOCK, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [ln for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    # the kernel's own name, which a profiler trace's op event starts with
+    for ln in calls:
+        assert re.search(rf"%guided_{name}_update(\.\d+)? = ", ln), ln
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])

@@ -464,3 +464,84 @@ def test_build_chunk_step_shapes():
                     {"x": jnp.arange(6.0).reshape(3, 2)})
     assert float(p["w"]) == 15.0 and int(g) == 3
     assert m["loss"].shape == (3,)
+
+
+# ----------------------------------------------- profiler spans, phase scopes
+
+
+def _host_events(trace_dir):
+    """The host events of the trace under `trace_dir`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    return [e for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+
+
+def test_fit_writes_its_spans_into_a_profiler_trace(tmp_path):
+    """Under a profiler session the chunked, prefetched fit (sizes [2, 2, 2],
+    a snapshot every 2 steps) writes each host span of the loop and of the
+    prefetch worker, as often as the work happens; the trajectory is the one
+    the same fit follows with no session."""
+    from collections import Counter
+
+    def spec(d):
+        return _spec(chunk_steps=2, prefetch=True, ckpt_dir=str(tmp_path / d),
+                     ckpt_every=2, keep_last=0)
+
+    def on_step(step, m, params):
+        pass
+
+    plain = Trainer.from_spec(spec("plain")).fit(on_step=on_step)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        traced = Trainer.from_spec(spec("traced")).fit(on_step=on_step)
+    assert traced.history == plain.history
+
+    events = _host_events(str(tmp_path / "trace"))
+    n = Counter(e.name for e in events)
+    blocks = 3
+    assert n["fit.dispatch"] == n["fit.input_wait"] == n["fit.on_step"] == blocks
+    assert n["fit.compile"] == 1  # one chunk shape, compiled by the first dispatch
+    assert n["fit.checkpoint"] == blocks + 1  # every 2 steps, and the final save
+    assert n["prefetch.put"] == blocks
+    # the worker's last draw finds the end of the stream, if it gets there
+    # before the loop closes the prefetcher
+    assert n["prefetch.make"] in (blocks, blocks + 1)
+    # each dispatch is a step span numbered by its chunk's first step
+    steps = [dict(e.stats)["step_num"] for e in events if e.name == "fit.dispatch"]
+    assert sorted(steps) == [0, 2, 4]
+
+
+def test_train_step_ops_carry_their_phase_scope():
+    """Every matmul of the compiled guided_fused SGD step (remat on, as on the
+    chip) names its phase in its op_name: the forward (its backward is
+    `transpose(jvp(forward))`, its recompute `rematted_computation`), the
+    loss head, the update or the guided bookkeeping."""
+    import re
+
+    from repro.data import make_batch_for
+    from repro.engine import mesh as M
+    from repro.optim import constant, get_optimizer
+
+    spec = _spec("guided_fused", "ssgd", model_overrides=TINY + (("remat", "full"),))
+    cfg, gcfg = spec.model_config(), spec.to_guided_config()
+    opt = get_optimizer("sgd")
+    strat = Trainer.from_spec(spec).strategy
+    step = M.build_train_step(cfg, gcfg, opt, M.build_ctx("local"),
+                              constant(1e-2), n_workers=2, strategy=strat)
+    params, _, gstate = M.init_train_state(
+        jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2, strategy=strat)
+    batch = {k: jnp.asarray(v) for k, v in make_batch_for(cfg, 8, 4, seed=0).items()}
+    text = jax.jit(step).lower(params, gstate, batch).compile().as_text()
+    names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if n.endswith(("dot_general", "conv_general_dilated"))]
+    assert names
+    phases = ("/jvp(forward)/", "/transpose(jvp(forward))/", "/loss_head/",
+              "/update/", "/guided/")
+    for name in names:
+        assert any(p in name + "/" for p in phases), name
+    for part in ("/loss_head/", "/transpose(jvp(forward))/", "/rematted_computation/"):
+        assert any(part in n for n in names), part
