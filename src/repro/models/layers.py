@@ -3,7 +3,9 @@ FFN (SwiGLU / GELU), embedding and logits head.
 
 All layer `apply` functions are pure; params are pytrees of jnp arrays (already
 unboxed). Attention dispatches between the XLA einsum implementation (used for
-dry-run lowering and CPU tests) and the Pallas kernels in repro.kernels.
+dry-run lowering and CPU tests) and the Pallas kernels in repro.kernels; on a
+TPU, causal self-attention at the shapes `splash_blocks` admits runs as JAX's
+Pallas splash kernels, forward and backward.
 """
 from __future__ import annotations
 
@@ -38,13 +40,16 @@ def rope_freqs(d_head: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, d_head, 2, dtype=np.float32) / d_head))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, d_head); positions: (..., S) int32."""
+def apply_rope(x, positions, theta: float, scale: float = 1.0):
+    """x: (..., S, H, d_head); positions: (..., S) int32. `scale` multiplies
+    the rotation in float32, before the one cast back to x's dtype."""
     d_head = x.shape[-1]
     freqs = jnp.asarray(rope_freqs(d_head, theta))
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, d/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = scale * cos, scale * sin
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -75,6 +80,67 @@ def _full_attention_xla(q, k, v, *, causal: bool, q_offset, scale):
     else:
         mask = jnp.ones((1, 1, 1, Sq, Skv), bool)
     return _sdpa(q, k, v, mask, scale)
+
+
+#: the splash kernels' query and key block (forward, dq and dkv), largest
+#: first: a sequence takes the first that divides it. 512 from a sweep at
+#: 8 x 1024, d_head 128 on a TPU v5e (`benchmarks/splash_blocks.py`)
+SPLASH_BLOCKS = (512, 256, 128)
+#: head sizes at which the splash kernels compile for a v5e and beat the XLA
+#: path on it (yi-9b's 128, minicpm-2b's 64)
+SPLASH_HEAD_DIMS = (64, 128)
+
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def splash_blocks(Sq: int, Skv: int, dh: int, *, causal: bool, window: int = 0,
+                  q_offset=0, distributed: bool = False):
+    """The splash kernels' block size for this attention, or None where the
+    XLA path serves it: off the TPU, non-causal, cross or offset attention, a
+    window shorter than the sequence, a sequence no block divides, a head
+    size the compiler refuses, and under a distributed mesh (a Pallas call
+    cannot be auto-partitioned)."""
+    if _backend() != "tpu" or not causal or distributed:
+        return None
+    if Sq != Skv or not isinstance(q_offset, int) or q_offset != 0:
+        return None
+    if (window and window < Sq) or dh not in SPLASH_HEAD_DIMS:
+        return None
+    return next((b for b in SPLASH_BLOCKS if Sq % b == 0), None)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(S: int, G: int, block: int, interpret: bool):
+    """Causal splash attention for one KV head and its G query heads, built
+    once per shape. Its mask tables are made eagerly, so a kernel first built
+    inside a trace holds no tracer."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    mask = sm.MultiHeadMask([sm.CausalMask((S, S))] * G)
+    blocks = sk.BlockSizes(block_q=block, block_kv=block, block_kv_compute=block,
+                           block_q_dkv=block, block_kv_dkv=block,
+                           block_kv_dkv_compute=block, block_q_dq=block,
+                           block_kv_dq=block)
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa_single_device(mask, block_sizes=blocks,
+                                                interpret=interpret)
+
+
+def _splash_attention(q, k, v, *, block: int, interpret: bool = False):
+    """Exact causal GQA by the Pallas splash kernels: online softmax block by
+    block, forward and backward, blocks above the diagonal skipped; the
+    scores never reach HBM. q: (B, S, K, G, dh), already scaled by
+    1/sqrt(dh); k, v: (B, S, K, dh). One MQA call per batch row and KV head."""
+    B, S, K, G, dh = q.shape
+    kernel = _splash_kernel(S, G, block, interpret)
+    qt = jnp.transpose(q, (0, 2, 3, 1, 4))  # (B, K, G, S, dh)
+    kt = jnp.transpose(k, (0, 2, 1, 3))  # (B, K, S, dh)
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    out = jax.vmap(jax.vmap(kernel))(qt, kt, vt)
+    return jnp.transpose(out, (0, 3, 1, 2, 4))  # (B, S, K, G, dh)
 
 
 def _swa_blocked_xla(q, k, v, *, window: int, scale):
@@ -163,17 +229,33 @@ def attention(
     window: int = 0,
     q_offset=0,
     impl: str = "xla",
+    distributed: bool = False,
+    prescaled: bool = False,
 ):
     """Grouped-query attention.
 
     q: (B, Sq, H, dh); k, v: (B, Skv, K, dh). Returns (B, Sq, H, dh).
-    window > 0 selects exact sliding-window causal attention.
+    window > 0 selects exact sliding-window causal attention. With
+    impl="xla", the shapes `splash_blocks` admits run as the splash kernels;
+    `prescaled` says q already carries 1/sqrt(dh) (`attn_apply` folds it into
+    RoPE there), and is allowed on that path only.
     """
     B, Sq, H, dh = q.shape
     K = n_kv_heads
     G = H // K
     scale = 1.0 / np.sqrt(dh)
     qg = q.reshape(B, Sq, K, G, dh)
+
+    block = None
+    if impl == "xla":
+        block = splash_blocks(Sq, k.shape[1], dh, causal=causal, window=window,
+                              q_offset=q_offset, distributed=distributed)
+    if prescaled and block is None:
+        raise ValueError("a prescaled q is for the splash kernels only")
+    if block is not None:
+        if not prescaled:
+            qg = (qg.astype(jnp.float32) * scale).astype(q.dtype)
+        return _splash_attention(qg, k, v, block=block).reshape(B, Sq, H, dh)
 
     if impl == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
@@ -239,15 +321,22 @@ def attn_init(key, cfg) -> dict:
     }
 
 
-def attn_apply(p, x, cfg, *, positions, k_cache=None, v_cache=None, cache_len=None):
+def attn_apply(p, x, cfg, *, positions, k_cache=None, v_cache=None, cache_len=None,
+               distributed: bool = False):
     """Returns (out, (new_k, new_v)) — new_k/new_v are this call's K/V entries
-    (pre-cache-write, post-RoPE), used by the caller to update caches."""
+    (pre-cache-write, post-RoPE), used by the caller to update caches.
+    `distributed`: the call runs under a distributed `ShardCtx`."""
     B, S, d = x.shape
     H, K, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = (x @ p["wq"]).reshape(B, S, H, dh)
     k = (x @ p["wk"]).reshape(B, S, K, dh)
     v = (x @ p["wv"]).reshape(B, S, K, dh)
-    q = apply_rope(q, positions, cfg.rope_theta)
+    window = cfg.sliding_window if cfg.causal else 0
+    # the splash kernels take no softmax scale: q carries it from its RoPE
+    splash = (k_cache is None and cfg.attn_impl == "xla"
+              and splash_blocks(S, S, dh, causal=cfg.causal, window=window,
+                                distributed=distributed) is not None)
+    q = apply_rope(q, positions, cfg.rope_theta, scale=1.0 / np.sqrt(dh) if splash else 1.0)
     k = apply_rope(k, positions, cfg.rope_theta)
 
     if k_cache is not None:
@@ -257,8 +346,10 @@ def attn_apply(p, x, cfg, *, positions, k_cache=None, v_cache=None, cache_len=No
             q, k, v,
             n_kv_heads=K,
             causal=cfg.causal,
-            window=cfg.sliding_window if cfg.causal else 0,
+            window=window,
             impl=cfg.attn_impl,
+            distributed=distributed,
+            prescaled=splash,
         )
     return out.reshape(B, S, H * dh) @ p["wo"], (k, v)
 

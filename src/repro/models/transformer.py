@@ -289,7 +289,8 @@ def layer_apply(lp, x, cfg, ctx, i, positions, cache=None, t=None):
             out = sharded_decode_attention(q, k_full, v_full, clen, cfg, ctx)
             att = out.reshape(B, S, H * dh) @ lp["mixer"]["wo"]
         else:
-            att, (k, v) = L.attn_apply(lp["mixer"], h, cfg, positions=positions)
+            att, (k, v) = L.attn_apply(lp["mixer"], h, cfg, positions=positions,
+                                       distributed=ctx.distributed)
             if cache is not None:  # prefill: write the (window of the) sequence
                 s_c = cache["k"].shape[1]
                 S = k.shape[1]

@@ -12,6 +12,7 @@ from repro.kernels.guided_update.ops import guided_sgd_update, guided_rmsprop_up
 from repro.kernels.guided_update.ref import guided_rmsprop_update_ref, guided_sgd_update_ref
 from repro.kernels.selective_scan.ops import selective_scan
 from repro.kernels.selective_scan.ref import selective_scan_ref
+from repro.models import layers as L
 
 RNG = np.random.default_rng(0)
 
@@ -49,6 +50,146 @@ def test_flash_attention_block_shapes(bq, bk):
     out = flash_attention(q, k, v, causal=True, bq=bq, bk=bk)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+# ------------------------------------------------- splash attention (training)
+
+
+@pytest.fixture(scope="module", params=L.SPLASH_HEAD_DIMS, ids=lambda d: f"dh{d}")
+def splash_vs_xla(request):
+    """Output and (dq, dk, dv) of causal GQA at B 2, S 256, 4 query and 2 KV
+    heads in bf16, by the splash kernels (interpret mode, 128 blocks) and by
+    the XLA path: {name: (splash, xla)} in float32."""
+    dh = request.param
+    B, S, H, K = 2, 256, 4, 2
+    q = randn(B, S, K, H // K, dh, dtype=jnp.bfloat16)
+    k, v = randn(B, S, K, dh, dtype=jnp.bfloat16), randn(B, S, K, dh, dtype=jnp.bfloat16)
+    ct = randn(B, S, K, H // K, dh, dtype=jnp.bfloat16)
+    scale = 1.0 / np.sqrt(dh)
+
+    def splash(q, k, v):
+        qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        return L._splash_attention(qs, k, v, block=128, interpret=True)
+
+    def xla(q, k, v):
+        return L._full_attention_xla(q, k, v, causal=True, q_offset=0, scale=scale)
+
+    out = {}
+    for name, f in (("splash", splash), ("xla", xla)):
+        loss = lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * ct)  # noqa: E731
+        o = jax.jit(f)(q, k, v)
+        assert o.dtype == jnp.bfloat16 and o.shape == q.shape
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        out[name] = [np.asarray(x, np.float32) for x in (o, *grads)]
+    return {n: (a, b) for n, a, b in zip(("out", "dq", "dk", "dv"), out["splash"], out["xla"])}
+
+
+@pytest.mark.parametrize("name", ["out", "dq", "dk", "dv"])
+def test_splash_attention_matches_xla(splash_vs_xla, name):
+    got, want = splash_vs_xla[name]
+    # Both paths round q (scaled or not), the probabilities or the output to
+    # bf16 once each, at different points (the kernel scales q before its
+    # cast, keeps its running softmax in float32, and multiplies by v in
+    # float32): a few bf16 epsilons (2^-8) of the norm apart
+    rel = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert rel < 1e-2, rel
+    # and no element further than 4 bf16 ulps of the largest magnitude
+    # (a masked position leaking into the sum would be off by O(1))
+    ulp = 2.0 ** (np.floor(np.log2(np.abs(want).max())) - 7)
+    assert np.abs(got - want).max() <= 4 * ulp
+
+
+#: attention calls the guard must hand to the XLA path:
+#: (Sq, Skv, dh, causal, window, q_offset, distributed)
+XLA_CASES = {
+    "window-below-S": (256, 256, 128, True, 128, 0, False),
+    "non-causal": (256, 256, 128, False, 0, 0, False),
+    "q-offset": (256, 256, 128, True, 0, 128, False),
+    "cross-Sq-ne-Skv": (128, 256, 128, True, 0, 0, False),
+    "S-below-block": (64, 64, 128, True, 0, 0, False),
+    "S-not-a-block-multiple": (320, 320, 128, True, 0, 0, False),
+    "head-dim-refused": (256, 256, 32, True, 0, 0, False),
+    "distributed-ctx": (256, 256, 128, True, 0, 0, True),
+}
+
+
+def _attention_case(case, seed=0):
+    Sq, Skv, dh, causal, window, q_offset, distributed = case
+    r = np.random.default_rng(seed)
+    q = jnp.asarray(r.standard_normal((1, Sq, 4, dh)), jnp.bfloat16)
+    k, v = (jnp.asarray(r.standard_normal((1, Skv, 2, dh)), jnp.bfloat16) for _ in range(2))
+    kw = dict(n_kv_heads=2, causal=causal, window=window, q_offset=q_offset,
+              distributed=distributed)
+    return q, k, v, kw
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("case", list(XLA_CASES), ids=list(XLA_CASES))
+def test_attention_dispatch_keeps_xla_path(monkeypatch, case, on_tpu):
+    """On the CPU every call, and on a TPU every shape the guard refuses,
+    runs today's XLA path: the same numbers, and the kernel never called."""
+    q, k, v, kw = _attention_case(XLA_CASES[case])
+    want = np.asarray(L.attention(q, k, v, **kw), np.float32)
+
+    def no_kernel(*a, **k):
+        raise AssertionError("the splash kernel was called")
+
+    monkeypatch.setattr(L, "_splash_attention", no_kernel)
+    if on_tpu:
+        monkeypatch.setattr(L, "_backend", lambda: "tpu")
+    Sq, Skv, dh = q.shape[1], k.shape[1], q.shape[-1]
+    assert L.splash_blocks(Sq, Skv, dh, causal=kw["causal"], window=kw["window"],
+                           q_offset=kw["q_offset"], distributed=kw["distributed"]) is None
+    np.testing.assert_array_equal(np.asarray(L.attention(q, k, v, **kw), np.float32), want)
+
+
+@pytest.mark.parametrize("S,window", [(256, 0), (1024, 0), (512, 512)])
+def test_attention_dispatch_takes_splash_on_tpu(monkeypatch, S, window):
+    """Causal self-attention with no window shorter than S, at a block
+    multiple, on a TPU and off a distributed mesh: the kernel path."""
+    monkeypatch.setattr(L, "_backend", lambda: "tpu")
+    block = L.splash_blocks(S, S, 128, causal=True, window=window)
+    assert block in L.SPLASH_BLOCKS and S % block == 0
+    assert block == max(b for b in L.SPLASH_BLOCKS if S % b == 0)
+    # a prescaled q belongs to the kernel path only
+    q, k, v, kw = _attention_case(XLA_CASES["non-causal"])
+    with pytest.raises(ValueError):
+        L.attention(q, k, v, prescaled=True, **kw)
+
+
+def test_attn_apply_on_the_kernel_path_folds_the_scale_into_rope(monkeypatch):
+    """attn_apply with the kernel (interpret mode) and with the XLA path agree:
+    1/sqrt(d_head) is applied once, in q's RoPE."""
+    from repro.configs import get_config
+    from repro.models.module import split_params
+
+    cfg = get_config("yi_9b").reduced().replace(d_model=256, n_heads=2, n_kv_heads=1,
+                                               sliding_window=0)
+    assert cfg.d_head == 128 and cfg.attn_impl == "xla"
+    p, _ = split_params(L.attn_init(jax.random.PRNGKey(0), cfg))
+    x = randn(2, 256, cfg.d_model, dtype=jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(256)[None], (2, 256))
+    p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+    want, _ = L.attn_apply(p, x, cfg, positions=pos)
+
+    kernel = L._splash_attention
+    called = []
+
+    def interpreted(q, k, v, *, block):
+        called.append(block)
+        return kernel(q, k, v, block=block, interpret=True)
+
+    monkeypatch.setattr(L, "_backend", lambda: "tpu")
+    monkeypatch.setattr(L, "_splash_attention", interpreted)
+    got, _ = L.attn_apply(p, x, cfg, positions=pos)
+    assert called
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # the projections' bf16 rounding on top of the attention's (above)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-2
+    # under a distributed ctx the same call keeps XLA
+    called.clear()
+    L.attn_apply(p, x, cfg, positions=pos, distributed=True)
+    assert not called
 
 
 # --------------------------------------------------------------- flash decode
