@@ -22,7 +22,10 @@ ap.add_argument("--layers", type=int, default=12)
 ap.add_argument("--mesh", default="local")
 args = ap.parse_args()
 
-# minicpm-2b family at ~135M: 12 layers x d_model 576, d_ff 2304 + tied 122k-vocab embed
+# minicpm-2b family at ~135M: 12 layers x d_model 576, d_ff 2304 + tied 122k-vocab embed.
+# It keeps MiniCPM-2B's published multipliers at this width and depth (embeddings
+# x 12, residual branches x 1.4/sqrt(40), logits x 256/2304), not the muP values
+# a 576-wide, 12-layer MiniCPM would take (logits x 256/576).
 argv = [
     "--arch", "minicpm-2b",
     "--layers", str(args.layers), "--d-model", str(args.d_model), "--d-ff", "2304",

@@ -81,6 +81,14 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # MiniCPM's published scalings; None (every other model) emits no op. The
+    # token embeddings times `scale_emb`; each residual branch (attention,
+    # mamba, ffn, moe) times `residual_scale`; the final normed state times
+    # `logit_scale` before the head. Constants of the published model: a
+    # depth or width override leaves them as they are.
+    scale_emb: Optional[float] = None
+    residual_scale: Optional[float] = None
+    logit_scale: Optional[float] = None
 
     # which attention implementation ("xla" for dry-run lowering, "pallas" on TPU)
     attn_impl: str = "xla"

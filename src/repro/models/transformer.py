@@ -242,6 +242,20 @@ def sharded_decode_attention(q, k_cache, v_cache, cache_len, cfg, ctx: ShardCtx)
 # ------------------------------------------------------------- block apply
 
 
+def _scaled(x, s):
+    """x times a published scalar, the product taken in float32 and rounded
+    once to x's dtype."""
+    return (x.astype(jnp.float32) * s).astype(x.dtype)
+
+
+def _residual(x, y, cfg):
+    """x plus the branch y, times `cfg.residual_scale` where the model has
+    one (xLSTM blocks add their own residual and take none)."""
+    if cfg.residual_scale is not None:
+        y = _scaled(y, cfg.residual_scale)
+    return x + y
+
+
 def layer_apply(lp, x, cfg, ctx, i, positions, cache=None, t=None):
     """Apply layer i of a super-block. Returns (x, aux, new_cache)."""
     mk = mixer_kind(cfg, i)
@@ -312,14 +326,14 @@ def layer_apply(lp, x, cfg, ctx, i, positions, cache=None, t=None):
                     k_cache = jnp.zeros_like(cache["k"]).at[:, slots].set(kw.astype(cache["k"].dtype))
                     v_cache = jnp.zeros_like(cache["v"]).at[:, slots].set(vw.astype(cache["v"].dtype))
                     new_cache = {"k": k_cache, "v": v_cache}
-        x = x + att
+        x = _residual(x, att, cfg)
 
     elif mk == "mamba":
         h = L.rmsnorm(x, lp["norm1"], cfg.norm_eps)
         conv = cache["conv"] if cache is not None else None
         ssm = cache["ssm"] if cache is not None else None
         y, (new_conv, new_ssm) = M.mamba_apply(lp["mixer"], h, cfg, conv, ssm, impl=cfg.attn_impl if cfg.attn_impl == "pallas" else "xla")
-        x = x + y
+        x = _residual(x, y, cfg)
         if cache is not None:
             new_cache = {"conv": new_conv.astype(cache["conv"].dtype), "ssm": new_ssm}
 
@@ -339,10 +353,10 @@ def layer_apply(lp, x, cfg, ctx, i, positions, cache=None, t=None):
     if fk is not None:
         h = L.rmsnorm(x, lp["norm2"], cfg.norm_eps)
         if fk == "dense":
-            x = x + L.ffn_apply(lp["ffn"], h)
+            x = _residual(x, L.ffn_apply(lp["ffn"], h), cfg)
         else:
             y, aux_moe = MOE.moe_apply(lp["ffn"], h, cfg, ctx)
-            x = x + y
+            x = _residual(x, y, cfg)
             aux = aux + aux_moe
     return x, aux, new_cache
 
@@ -364,13 +378,22 @@ def block_apply(bp, x, cfg, ctx, positions, caches=None, t=None):
 # ------------------------------------------------------------ full forward
 
 
+def _embed_tokens(params, tokens, cfg):
+    """Token embeddings in the compute dtype, times `cfg.scale_emb` where the
+    model has one: the one lookup of training, prefill and decode."""
+    x = L.embed_lookup(params["embed"], tokens).astype(cfg.dtype)
+    if cfg.scale_emb is not None:
+        x = _scaled(x, cfg.scale_emb)
+    return x
+
+
 def _embed_inputs(params, batch, cfg):
     if cfg.audio_frontend:
         x = batch["frames"].astype(cfg.dtype)
         mask = batch["mask_positions"]
         x = jnp.where(mask[..., None], params["mask_emb"].astype(cfg.dtype), x)
         return x
-    x = L.embed_lookup(params["embed"], batch["tokens"]).astype(cfg.dtype)
+    x = _embed_tokens(params, batch["tokens"], cfg)
     if cfg.arch_type == "vlm" and "patches" in batch:
         P_ = batch["patches"].shape[1]
         x = jnp.concatenate([x[:, :1], batch["patches"].astype(cfg.dtype), x[:, 1 + P_ :]], axis=1)
@@ -435,6 +458,8 @@ def _stack_scan(params, x, cfg, ctx, positions, caches=None, t=None):
 
 def _head(params, x, cfg):
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    if cfg.logit_scale is not None:
+        x = _scaled(x, cfg.logit_scale)
     if cfg.audio_frontend:
         return x @ params["head"]
     return L.logits_head(params["embed"], x)
@@ -483,7 +508,7 @@ def decode_step(params, caches, tokens, t, cfg, ctx: ShardCtx = LOCAL_CTX):
     every slot advances at its own depth). Returns (logits (B,V), new caches)."""
     if cfg.audio_frontend:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
-    x = L.embed_lookup(params["embed"], tokens).astype(cfg.dtype)
+    x = _embed_tokens(params, tokens, cfg)
     B = x.shape[0]
     tv = jnp.asarray(t, jnp.int32)
     if tv.ndim == 0:

@@ -1,12 +1,20 @@
 """Serving-engine tests (repro.serve, DESIGN.md §7):
 
   * token-for-token parity of the continuous engine vs. the lockstep loop for
-    equal-length requests (greedy AND seeded stochastic sampling — the two
+    equal-length requests (seeded temperature AND top-k sampling — the two
     paths share the key-split protocol);
   * completion / slot-recycling with staggered prompt lengths, max-token
     limits and EOS;
   * per-slot position decode equals per-request sequential decode (pool of
     heterogeneous-depth requests vs. each request run alone);
+
+Greedy decoding of the random-weight reduced MiniCPM repeats the last prompt
+token (its embedding, times scale_emb, dominates the residual stream and the
+tied head scores it highest), whatever the cache holds; and its logits are so
+flat (the head's 256/2304 scale) that a seeded draw hardly depends on them.
+So the stream comparisons draw with per-request seeded sampling, assert that
+every compared stream varies, and compare the logits each token was drawn
+from, found by the request's own key chain whatever slot or batch it ran in.
   * the sampling layer (greedy = temperature 0 = top-k 1 argmax; top-k draws
     stay inside the top-k set; determinism; parameter validation).
 """
@@ -25,6 +33,11 @@ from repro.serve import (
     lockstep_generate,
     sample_tokens,
 )
+from repro.serve import engine as serve_engine
+
+#: seeds of the compared requests' draws: clear of the all-zero key of an
+#: empty pool slot (PRNGKey(0)) and of the chain it advances along
+SEED0 = 100
 
 
 @pytest.fixture(scope="module")
@@ -52,32 +65,95 @@ def _by_id(comps):
     return {c.request_id: c for c in comps}
 
 
+def _seeded(i, **kw):
+    """Request i's own seeded draw at temperature 1 (`kw` overrides)."""
+    return SamplingParams(**{"method": "temperature", "temperature": 1.0,
+                             "seed": SEED0 + i, **kw})
+
+
+def _varied(tokens):
+    """A compared stream must not be one token repeated: a constant stream
+    matches whether or not the cache and the slots are right."""
+    assert len(set(tokens)) > 1, tokens
+
+
+class _LogitsBySeededKey:
+    """Records every row of logits the engine samples from under the key it
+    draws with (prefill, admission and decode all sample through
+    `serve.engine.sample_tokens`). A request's keys are its own chain from
+    PRNGKey(seed), split once per token, so `rows(seed, n)` finds its n rows
+    in whatever slot and batch they ran."""
+
+    def __init__(self, monkeypatch):
+        self.seen = {}
+        sample = serve_engine.sample_tokens
+
+        def recording(logits, keys, *a):
+            jax.debug.callback(self._put, logits, keys)
+            return sample(logits, keys, *a)
+
+        monkeypatch.setattr(serve_engine, "sample_tokens", recording)
+
+    def _put(self, logits, keys):
+        for row, key in zip(np.asarray(logits), np.asarray(keys, np.uint32)):
+            self.seen.setdefault(key.tobytes(), []).append(row)
+
+    def take(self):
+        """The rows recorded since the last take."""
+        jax.effects_barrier()
+        seen, self.seen = self.seen, {}
+        return seen
+
+    @staticmethod
+    def rows(seen, seed, n):
+        key, out = jax.random.PRNGKey(seed), []
+        for _ in range(n):
+            (row,) = seen[np.asarray(key, np.uint32).tobytes()]
+            out.append(row)
+            key = jax.random.split(key)[0]
+        return np.stack(out)
+
+
+def _same_logits(a, b):
+    """Both sides compute each row in float32 from the same weights; only the
+    batch they ran in differs, which moves a row by float32 rounding (at most
+    1.4e-6 of its norm here). A request's cache in another slot, or another
+    slot's length read for it, moves a row by 0.3 of its norm or more."""
+    gap = np.linalg.norm(a - b, axis=-1) / np.linalg.norm(b, axis=-1)
+    assert gap.max() < 1e-5, gap
+
+
 # ------------------------------------------------- (a) lockstep parity
 
 
 @pytest.mark.parametrize("sampling", [
-    SamplingParams(),  # greedy
-    SamplingParams(method="topk", top_k=20, temperature=0.8),
+    {},  # temperature 1
+    {"method": "topk", "top_k": 20, "temperature": 0.8},
 ])
-def test_continuous_matches_lockstep_equal_lengths(dense, sampling):
+def test_continuous_matches_lockstep_equal_lengths(dense, sampling, monkeypatch):
     """With equal prompt lengths the barriered loop has no padding flaw, so
-    the continuous engine must reproduce it token for token — including
-    stochastic sampling, which shares the per-request key-split protocol."""
+    the continuous engine must reproduce it token for token — stochastic
+    sampling included, which shares the per-request key-split protocol. (The
+    sampler's greedy branch: test_sampling_greedy_paths_agree.)"""
     cfg, params = dense
     prompts = _prompts(cfg, [12, 12, 12, 12])
 
     def reqs():
-        return [Request(list(p), max_new_tokens=6,
-                        sampling=SamplingParams(**{**sampling.__dict__, "seed": i}),
+        return [Request(list(p), max_new_tokens=6, sampling=_seeded(i, **sampling),
                         request_id=i)
                 for i, p in enumerate(prompts)]
 
+    rec = _LogitsBySeededKey(monkeypatch)
     engine = ServeEngine(params, cfg, max_batch=4, max_len=32)
     cont = _by_id(engine.run(reqs()))
+    cont_logits = rec.take()
     lock = _by_id(lockstep_generate(engine, reqs())[0])
+    lock_logits = rec.take()
     assert set(cont) == set(lock) == {0, 1, 2, 3}
     for i in cont:
+        _varied(cont[i].tokens)
         assert cont[i].tokens == lock[i].tokens, i
+        _same_logits(rec.rows(cont_logits, SEED0 + i, 6), rec.rows(lock_logits, SEED0 + i, 6))
 
 
 # --------------------------------- (b) staggered completion / recycling
@@ -112,13 +188,15 @@ def test_slot_recycling_staggered_lengths(dense):
 def test_eos_frees_slot_early(dense):
     cfg, params = dense
     (prompt,) = _prompts(cfg, [10])
+    sampling = _seeded(0)
     engine = ServeEngine(params, cfg, max_batch=1, max_len=64)
-    (full,) = engine.run([Request(list(prompt), max_new_tokens=8)])
+    (full,) = engine.run([Request(list(prompt), max_new_tokens=8, sampling=sampling)])
     assert full.finish_reason == "length"
     # rerun with EOS set to the 4th generated token: must stop there
     eos = full.tokens[3]
+    assert eos not in full.tokens[:3], full.tokens
     engine2 = ServeEngine(params, cfg, max_batch=1, max_len=64, eos_id=eos)
-    (cut,) = engine2.run([Request(list(prompt), max_new_tokens=8)])
+    (cut,) = engine2.run([Request(list(prompt), max_new_tokens=8, sampling=sampling)])
     assert cut.finish_reason == "eos"
     assert cut.tokens == full.tokens[:4]
 
@@ -140,22 +218,27 @@ def test_streaming_callback_matches_completion(xlstm):
 
 
 @pytest.mark.parametrize("arch_fixture", ["dense", "xlstm"])
-def test_per_slot_decode_matches_sequential(request, arch_fixture):
+def test_per_slot_decode_matches_sequential(request, arch_fixture, monkeypatch):
     """A pool of requests at heterogeneous depths (per-slot position vector)
     must produce exactly the tokens each request gets when decoded alone
     (pool of 1): cross-slot isolation of the batched decode."""
     cfg, params = request.getfixturevalue(arch_fixture)
     lens = [5, 9, 12, 7, 16]
     gens = [6, 4, 8, 3, 5]
-    reqs = [Request(p, max_new_tokens=g, request_id=i)
+    reqs = [Request(p, max_new_tokens=g, sampling=_seeded(i), request_id=i)
             for i, (p, g) in enumerate(zip(_prompts(cfg, lens), gens))]
+    rec = _LogitsBySeededKey(monkeypatch)
     pool = ServeEngine(params, cfg, max_batch=3, max_len=32)
     pooled = _by_id(pool.run(reqs))
+    pooled_logits = rec.take()
 
     solo_engine = ServeEngine(params, cfg, max_batch=1, max_len=32)
     for i, (p, g) in enumerate(zip(_prompts(cfg, lens), gens)):
-        (solo,) = solo_engine.run([Request(p, max_new_tokens=g, request_id=i)])
+        (solo,) = solo_engine.run([Request(p, max_new_tokens=g, sampling=_seeded(i),
+                                           request_id=i)])
+        _varied(pooled[i].tokens)
         assert pooled[i].tokens == solo.tokens, i
+        _same_logits(rec.rows(pooled_logits, SEED0 + i, g), rec.rows(rec.take(), SEED0 + i, g))
 
 
 def test_decode_step_accepts_scalar_and_vector_t(dense):
