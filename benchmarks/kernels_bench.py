@@ -158,7 +158,7 @@ def _interpret_diag(n: int = 65536):
 
 def bench_fused(small: bool = False) -> dict:
     """The structured BENCH_kernels.json payload."""
-    from repro.kernels import autotune, default_interpret
+    from repro.kernels import default_interpret
 
     sizes = SMALL_SIZES if small else SIZES
     entries = [_fused_case(opt, n)
@@ -170,7 +170,6 @@ def bench_fused(small: bool = False) -> dict:
         "device_kind": getattr(dev, "device_kind", "unknown"),
         "interpret": default_interpret(),
         "sizes": list(sizes),
-        "autotune_cache": autotune.cache_path(),
         "entries": entries,
         "interpret_diag": _interpret_diag(),
     }

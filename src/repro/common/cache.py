@@ -9,8 +9,6 @@ process id) never hits.
     `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it and this sets nothing;
     otherwise the cache goes to `.cache/jax`. Called by the launchers and
     `chip_smoke.py` before their first compile.
-  * `checkout_cache(name)` — `.cache/<name>`; the fused-kernel autotuner keeps
-    its winners in `.cache/autotune` (unless `REPRO_AUTOTUNE_CACHE` is set).
 """
 from __future__ import annotations
 
@@ -21,10 +19,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
 
-def checkout_cache(name: str) -> str:
-    return os.path.join(ROOT, ".cache", name)
-
-
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory."""
     import jax
@@ -32,6 +26,6 @@ def enable_compile_cache() -> str:
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    path = checkout_cache("jax")
+    path = os.path.join(ROOT, ".cache", "jax")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -35,13 +35,17 @@ weights (promote_types(w.dtype, float32)), so the float64 parity runs of the
 scan backend reproduce the numpy reference loop exactly while bf16/f32 mesh
 weights keep the f32 arithmetic the TPU path compiles to.
 
-Tiling: 2-D `(rows, cols)` blocks over each leaf's own layout via
-`repro.kernels._tile_grid`. `block` is the element count of one block;
-`block=None` (the default) resolves through `repro.kernels.autotune.tuned_block`
-— a per (kernel, dtype, backend+device) measured winner among the blocks that
-fit the chip's fast memory, falling back to 64k elements where sweeping is
-meaningless. Resolution happens at trace time, so the tuned block is a static
-of the enclosing jit.
+Tiling: blocks over each leaf's own HBM layout via `repro.kernels.tiling`.
+`block` is the element budget of one block; `block=None` (the default)
+derives it from the streams the kernel opens (`repro.kernels.stream_block`:
+every array in and out at its dtype), so the block is a pure function of the
+leaf's shape, its dtypes and the kernel, and a static of the enclosing jit.
+
+Where `lam` is a Python number equal to 0 at trace time (the mesh trainer's
+strategies without DC-ASGD's term), the kernel is launched without the
+`w_stale` operand and reads `w` in its place: `lam * g*g*(w - w)` is the
+same zero, and the update streams 6 B per bf16 parameter (sgd) instead of 8.
+A traced `lam` (the delay-simulation scan) keeps the stream.
 """
 from __future__ import annotations
 
@@ -52,40 +56,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _tile_grid, default_interpret  # noqa: F401  (re-export: ops.py, delaysim)
-from repro.kernels.autotune import tuned_block
+from repro.kernels import default_interpret, stream_block, tiling
 
 
 def _compute_dtype(dtype):
     return jnp.promote_types(dtype, jnp.float32)
 
 
-def _resolve(block, interpret, kernel_name, dtype):
+def _streams(kernel_fn, w, g, w_stale, lam):
+    """The kernel and its leading operands: `(w, g, w_stale)`, or `(w, g)`
+    where `lam` is a Python 0 (a trace-time constant). The stream-less kernel
+    reads `w` as its `w_stale`, so its result is bit for bit the one it
+    gives with `w_stale = w`."""
+    if isinstance(lam, (int, float)) and lam == 0:
+        def without_stale(w_ref, g_ref, *refs):
+            return kernel_fn(w_ref, g_ref, w_ref, *refs)
+
+        return without_stale, (w, g)
+    return kernel_fn, (w, g, w_stale)
+
+
+def _launch(kernel_fn, arrays, scalars, out_dtypes, block, interpret, name):
+    """One elementwise pallas_call over `arrays` (all of one leaf's shape),
+    each viewed and tiled as `repro.kernels.tiling` says, the scalar pack
+    riding along whole in SMEM as the last operand (Mosaic loads scalars only
+    from SMEM or VMEM; an ANY-space ref would need an explicit DMA). `name`
+    names the custom call in the compiled program and in a profiler trace
+    (`guided_sgd_update.3`). Returns the outputs in the leaf's shape."""
     if interpret is None:
         interpret = default_interpret()
     if block is None:
-        block = tuned_block(kernel_name, dtype)
-    return block, interpret
-
-
-def _launch(kernel_fn, views, scalars, block_shape, grid, out_dtypes,
-            interpret, name):
-    """One elementwise pallas_call over `_tile_grid` views: every array in/out
-    tiled `block_shape`, the scalar pack riding along whole in SMEM (Mosaic
-    loads scalars only from SMEM or VMEM; an ANY-space ref would need an
-    explicit DMA). `name` names the custom call in the compiled program and
-    in a profiler trace (`guided_sgd_update.3`)."""
-    shape = views[0].shape
-    bspec = lambda: pl.BlockSpec(block_shape, lambda i, j: (i, j))
-    return pl.pallas_call(
+        block = stream_block([a.dtype for a in arrays] + list(out_dtypes))
+    shape = arrays[0].shape
+    view, block_shape, grid = tiling(shape, block)
+    bspec = lambda: pl.BlockSpec(block_shape, lambda i, j, k: (i, j, k))
+    outs = pl.pallas_call(
         kernel_fn,
         grid=grid,
-        in_specs=[bspec() for _ in views] + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+        in_specs=[bspec() for _ in arrays] + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bspec() for _ in out_dtypes],
-        out_shape=[jax.ShapeDtypeStruct(shape, d) for d in out_dtypes],
+        out_shape=[jax.ShapeDtypeStruct(view, d) for d in out_dtypes],
         interpret=interpret,
         name=name,
-    )(*views, scalars)
+    )(*(a.reshape(view) for a in arrays), scalars)
+    return [o.reshape(shape) for o in outs]
 
 
 def _sgd_kernel(w_ref, g_ref, ws_ref, scal_ref, out_ref):
@@ -164,44 +178,40 @@ def _adam_kernel(w_ref, g_ref, ws_ref, m_ref, v_ref, scal_ref, out_ref,
 def guided_sgd_update_raw(w, g, w_stale, lr, lam, *, block: int = None,
                           interpret: bool = None):
     """Fused update for one parameter leaf. Returns new w."""
-    name = "guided_sgd_update"
-    block, interpret = _resolve(block, interpret, name, w.dtype)
     ct = _compute_dtype(w.dtype)
     scalars = jnp.stack([jnp.asarray(lr, ct), jnp.asarray(lam, ct)])
-    views, bs, grid = _tile_grid(block, w, g, w_stale)
-    (out,) = _launch(_sgd_kernel, views, scalars, bs, grid, [w.dtype], interpret, name)
-    return out.reshape(w.shape)
+    kernel_fn, lead = _streams(_sgd_kernel, w, g, w_stale, lam)
+    (out,) = _launch(kernel_fn, lead, scalars, [w.dtype], block, interpret,
+                     "guided_sgd_update")
+    return out
 
 
 def guided_momentum_update_raw(w, g, w_stale, m, lr, lam, beta, *,
                                nesterov: bool = False, block: int = None,
                                interpret: bool = None):
     """Fused compensate + momentum accumulate + apply. Returns (new w, new m)."""
-    name = "guided_momentum_update"
-    block, interpret = _resolve(block, interpret, name, w.dtype)
     ct = _compute_dtype(w.dtype)
     scalars = jnp.stack([
         jnp.asarray(lr, ct), jnp.asarray(lam, ct), jnp.asarray(beta, ct),
     ])
-    views, bs, grid = _tile_grid(block, w, g, w_stale, m)
-    out, m_new = _launch(partial(_momentum_kernel, nesterov), views, scalars,
-                         bs, grid, [w.dtype, ct], interpret, name)
-    return out.reshape(w.shape), m_new.reshape(w.shape)
+    kernel_fn, lead = _streams(partial(_momentum_kernel, nesterov), w, g,
+                               w_stale, lam)
+    out, m_new = _launch(kernel_fn, (*lead, m), scalars, [w.dtype, ct], block,
+                         interpret, "guided_momentum_update")
+    return out, m_new
 
 
 def guided_rmsprop_update_raw(w, g, w_stale, r, lr, lam, beta, eps, *,
                               block: int = None, interpret: bool = None):
-    name = "guided_rmsprop_update"
-    block, interpret = _resolve(block, interpret, name, w.dtype)
     ct = _compute_dtype(w.dtype)
     scalars = jnp.stack([
         jnp.asarray(lr, ct), jnp.asarray(lam, ct),
         jnp.asarray(beta, ct), jnp.asarray(eps, ct),
     ])
-    views, bs, grid = _tile_grid(block, w, g, w_stale, r)
-    out, r_new = _launch(_rmsprop_kernel, views, scalars, bs, grid,
-                         [w.dtype, ct], interpret, name)
-    return out.reshape(w.shape), r_new.reshape(w.shape)
+    kernel_fn, lead = _streams(_rmsprop_kernel, w, g, w_stale, lam)
+    out, r_new = _launch(kernel_fn, (*lead, r), scalars, [w.dtype, ct], block,
+                         interpret, "guided_rmsprop_update")
+    return out, r_new
 
 
 def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
@@ -213,8 +223,6 @@ def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
     pre-rounded (1-b) factors match the reference's weak-typed promotion.
     Returns (new w, new m, new v).
     """
-    name = "guided_adam_update"
-    block, interpret = _resolve(block, interpret, name, w.dtype)
     ct = _compute_dtype(w.dtype)
     tct = jnp.asarray(t).astype(ct)
     scalars = jnp.stack([
@@ -224,7 +232,8 @@ def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
         1.0 - jnp.asarray(b1, ct) ** tct, 1.0 - jnp.asarray(b2, ct) ** tct,
         jnp.asarray(eps, ct),
     ])
-    views, bs, grid = _tile_grid(block, w, g, w_stale, m, v)
-    out, m_new, v_new = _launch(_adam_kernel, views, scalars, bs, grid,
-                                [w.dtype, ct, ct], interpret, name)
-    return out.reshape(w.shape), m_new.reshape(w.shape), v_new.reshape(w.shape)
+    kernel_fn, lead = _streams(_adam_kernel, w, g, w_stale, lam)
+    out, m_new, v_new = _launch(kernel_fn, (*lead, m, v), scalars,
+                                [w.dtype, ct, ct], block, interpret,
+                                "guided_adam_update")
+    return out, m_new, v_new

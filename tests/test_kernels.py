@@ -372,99 +372,85 @@ def test_fused_update_for_rejects_unfused_optimizer():
         fused_update_for("adagrad")
 
 
-# ------------------------------------------------------------------- autotune
+# ------------------------------------------------- derived block, lam = 0 stream
 
 
-def test_autotune_cache_roundtrip(tmp_path):
-    """Sweep once (injected deterministic probe), persist, then re-resolve
-    from the JSON with NO probe — simulating a fresh process on the same box."""
-    from repro.kernels import autotune
-
-    calls = []
-
-    def fake_measure(kernel, dtype, block):
-        calls.append(block)
-        return abs(block - 32768) + 1.0  # 32k is fastest by construction
-
-    autotune.clear_memo()
-    got = autotune.tuned_block("guided_adam_update", jnp.float32,
-                               dirname=str(tmp_path), measure=fake_measure)
-    assert got == 32768
-    # only the blocks that fit fast memory are swept (adam f32: 64 B/element)
-    assert sorted(calls) == sorted(autotune.candidates("guided_adam_update",
-                                                       jnp.float32))
-    assert max(calls) * 64 <= autotune.VMEM_STREAM_BYTES
-
-    path = autotune.cache_path(str(tmp_path))
-    import json
-    with open(path) as f:
-        data = json.load(f)
-    assert data["guided_adam_update.float32"] == 32768
-
-    autotune.clear_memo()  # fresh "process": memo gone, JSON remains
-    calls.clear()
-    again = autotune.tuned_block("guided_adam_update", jnp.float32,
-                                 dirname=str(tmp_path))
-    assert again == 32768
-    assert calls == []  # served from the persisted winners, no re-sweep
-
-    # and the memo now short-circuits the file read entirely
-    assert autotune.tuned_block("guided_adam_update", jnp.float32,
-                                dirname=str(tmp_path)) == 32768
-
-
-def test_autotune_interpret_returns_default_unswept(tmp_path, monkeypatch):
-    """On interpret backends (cpu) the sweep is skipped and nothing persists:
-    timing the emulator would tune the wrong thing."""
-    import os
-
-    from repro.kernels import autotune
-
-    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    autotune.clear_memo()
-    got = autotune.tuned_block("guided_sgd_update", jnp.float32,
-                               dirname=str(tmp_path))
-    assert got == autotune.DEFAULT_BLOCK
-    assert not os.path.exists(autotune.cache_path(str(tmp_path)))
-
-
-def test_autotune_tuned_block_drives_kernel_result_identical(tmp_path):
-    """The tuned block is a launch parameter only: same numbers at any block."""
-    from repro.kernels import autotune
+def _raw_update(name, lam):
+    """(fn(w, g, ws, *acc), n_acc) of one fused kernel at `lam`."""
     from repro.kernels.guided_update import kernel as K
 
-    autotune.clear_memo()
-    block = autotune.tuned_block(
-        "guided_momentum_update", jnp.float32, dirname=str(tmp_path),
-        measure=lambda k, d, b: float(b))  # smallest candidate wins
-    assert block == min(autotune.CANDIDATES)
+    if name == "sgd":
+        return (lambda w, g, ws: K.guided_sgd_update_raw(w, g, ws, 0.2, lam)), 0
+    if name == "momentum":
+        return (lambda w, g, ws, m: K.guided_momentum_update_raw(
+            w, g, ws, m, 0.2, lam, 0.9, nesterov=True)), 1
+    if name == "rmsprop":
+        return (lambda w, g, ws, r: K.guided_rmsprop_update_raw(
+            w, g, ws, r, 0.2, lam, 0.9, 1e-8)), 1
+    return (lambda w, g, ws, m, v: K.guided_adam_update_raw(
+        w, g, ws, m, v, 3, 0.2, lam, 0.9, 0.999, 1e-8)), 2
 
-    w = randn(1000)
-    g = randn(1000) * 0.01
+
+def _pallas_inputs(fn, *args):
+    (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+              if e.primitive.name == "pallas_call"]
+    return len(eqn.invars)
+
+
+@pytest.mark.parametrize("name", ["sgd", "momentum", "rmsprop", "adam"])
+def test_lam_zero_drops_w_stale_stream_bit_identical(name):
+    """A Python 0 for lam launches the kernel without its w_stale operand; a
+    traced 0 keeps it (the delay-simulation scan). The results are bit for bit
+    the same, though w_stale differs from w."""
+    shape = (3, 40, 2, 136)
+    w = randn(*shape)
+    g = randn(*shape) * 0.01
     ws = w + 0.05
-    m = jnp.abs(w) * 0.1
-    a = K.guided_momentum_update_raw(w, g, ws, m, 0.2, 0.04, 0.9, block=block)
-    b = K.guided_momentum_update_raw(w, g, ws, m, 0.2, 0.04, 0.9, block=256)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    acc = [jnp.abs(randn(*shape)) * 0.1 for _ in range(2)]
+    static, n_acc = _raw_update(name, 0.0)
+    traced, _ = _raw_update(name, jnp.asarray(0.0))
+    args = (w, g, ws, *acc[:n_acc])
+    # every array in, plus the scalar pack
+    assert _pallas_inputs(static, *args) == len(args)
+    assert _pallas_inputs(traced, *args) == len(args) + 1
+    for a, b in zip(jax.tree.leaves(static(*args)), jax.tree.leaves(traced(*args))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_autotune_sweep_inside_a_trace_runs_the_probes(tmp_path):
-    """The first resolution happens while the train step is traced; the
-    probes must still run on the device (concrete arrays), not be staged
-    into the step being traced — else the sweep times the tracer."""
-    from repro.kernels import autotune
+@pytest.mark.parametrize("shape", [(1000,), (37, 129), (3, 40, 2, 136), (5, 3, 700)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("block", [None, 256, 4096])
+def test_fused_update_same_result_at_any_tiling(shape, block):
+    """The block is a launch parameter only: any view and block of a leaf
+    (merged rows, kept last two dims, split last dim) gives the numbers of the
+    reference."""
     from repro.kernels.guided_update import kernel as K
+    from repro.kernels.guided_update import ref as R
 
-    seen = []
+    w = randn(*shape)
+    g = randn(*shape) * 0.01
+    ws = w + 0.05
+    m, v = jnp.abs(w) * 0.1, jnp.abs(w) * 0.05
+    hy = (3, 0.2, 0.04, 0.9, 0.999, 1e-8)
+    got = K.guided_adam_update_raw(w, g, ws, m, v, *hy, block=block)
+    for a, b in zip(got, R.guided_adam_update_ref(w, g, ws, m, v, *hy)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
-    def measure(kernel, dtype, block):
-        seen.append(isinstance(jnp.ones(2) + 1, jax.core.Tracer))
-        return float(block)
 
-    autotune.clear_memo()
-    step = jax.jit(lambda w: K.guided_sgd_update_raw(
-        w, w, w, 0.1, 0.0, block=autotune.tuned_block(
-            "guided_sgd_update", w.dtype, dirname=str(tmp_path), measure=measure)))
-    step(jnp.ones((16, 256)))
-    assert seen and not any(seen)
+@pytest.mark.parametrize("shape", [(13, 4096, 2, 11008), (10, 2304, 2, 5760),
+                                   (4096, 64000), (122753, 2304), (3, 4097, 5000)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_tiling_keeps_the_layout_and_splits_lanes_evenly(shape):
+    """The view is the leaf's own layout (leading dims merge only over a
+    second-minor dim that is a multiple of the sublanes), a block never
+    passes its budget, and a split last dim is split into equal parts."""
+    from repro.kernels import LANE, SUBLANES, stream_block, tiling
+
+    for block in (stream_block(["bfloat16"] * 3), stream_block(["bfloat16"] * 4 + ["float32"] * 4)):
+        view, bs, grid = tiling(shape, block)
+        assert int(np.prod(view)) == int(np.prod(shape)) and view[-1] == shape[-1]
+        if len(shape) >= 3 and shape[-2] % SUBLANES:
+            assert view[1:] == shape[-2:]
+        assert int(np.prod(bs)) <= block
+        assert bs[-1] == shape[-1] or (shape[-1] % bs[-1] == 0 and bs[-1] % LANE == 0)
+        assert grid == tuple(-(-a // b) for a, b in zip(view, bs))

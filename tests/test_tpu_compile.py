@@ -6,6 +6,7 @@ process may load the TPU library, and every xdist worker imports this file.
 The persistent compilation cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
+import importlib.util
 import os
 import re
 
@@ -15,11 +16,23 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.kernels import autotune
+from repro import kernels as RK
 from repro.kernels.guided_update import kernel as K
 
 #: yi-9b leaves: the FFN input projection, the embedding table, a norm
 LEAVES = [(4096, 11008), (64000, 4096), (4096,)]
+
+#: every distinct parameter leaf `model_init` makes at the benchmark cells'
+#: depths (yi-9b at 13 layers, minicpm-2b at 10), with its dtype
+CELL_LEAVES = {
+    "yi-9b": [((64000, 4096), "bfloat16"), ((4096, 64000), "bfloat16"),
+              ((13, 4096, 4096), "bfloat16"), ((13, 4096, 512), "bfloat16"),
+              ((13, 4096, 2, 11008), "bfloat16"), ((13, 11008, 4096), "bfloat16"),
+              ((13, 4096), "float32")],
+    "minicpm-2b": [((122753, 2304), "bfloat16"), ((10, 2304, 2, 5760), "bfloat16"),
+                   ((10, 5760, 2304), "bfloat16"), ((10, 2304, 2304), "bfloat16"),
+                   ((10, 2304), "float32")],
+}
 
 
 @pytest.fixture(scope="module")
@@ -45,31 +58,46 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _update(name, block):
-    """(fn, n_weight_arrays, n_accumulators) for one kernel, compiled (not
-    interpreted) at `block`."""
+def _update(name, block, lam=0.04):
+    """(fn, n_accumulators) for one kernel, compiled (not interpreted) at
+    `block` (None: the derived block)."""
     kw = dict(block=block, interpret=False)
     if name == "sgd":
-        return lambda w, g, ws: K.guided_sgd_update_raw(w, g, ws, 0.1, 0.04, **kw), 0
+        return lambda w, g, ws: K.guided_sgd_update_raw(w, g, ws, 0.1, lam, **kw), 0
     if name == "momentum":
         return (lambda w, g, ws, m: K.guided_momentum_update_raw(
-            w, g, ws, m, 0.1, 0.04, 0.9, **kw), 1)
+            w, g, ws, m, 0.1, lam, 0.9, **kw), 1)
+    if name == "rmsprop":
+        return (lambda w, g, ws, r: K.guided_rmsprop_update_raw(
+            w, g, ws, r, 0.1, lam, 0.9, 1e-8, **kw), 1)
     return (lambda w, g, ws, m, v: K.guided_adam_update_raw(
-        w, g, ws, m, v, 3, 0.1, 0.04, 0.9, 0.999, 1e-8, **kw), 2)
+        w, g, ws, m, v, 3, 0.1, lam, 0.9, 0.999, 1e-8, **kw), 2)
 
 
-def _compile(name, shape, dtype, block, sharding):
-    fn, n_acc = _update(name, block)
+def _compile(name, shape, dtype, block, sharding, lam=0.04):
+    fn, n_acc = _update(name, block, lam)
     acc = jnp.promote_types(dtype, jnp.float32)
     args = ([jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * 3
             + [jax.ShapeDtypeStruct(shape, acc, sharding=sharding)] * n_acc)
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernel_lines(compiled):
+    """The compiled program's custom calls as a profiler trace names them:
+    the whole instruction with every operand's shape."""
+    from jax._src.lib import xla_client as xc
+
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    (module,) = compiled.runtime_executable().hlo_modules()
+    return [ln.strip() for ln in module.to_string(opts).splitlines()
+            if "tpu_custom_call" in ln]
+
+
 @pytest.mark.parametrize("shape", LEAVES, ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
 def test_kernel_compiles_at_yi_9b_leaf(topo, one_chip, name, shape):
-    compiled = _compile(name, shape, jnp.bfloat16, autotune.DEFAULT_BLOCK, one_chip)
+    compiled = _compile(name, shape, jnp.bfloat16, None, one_chip)
     calls = [ln for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln]
     assert calls
     # the kernel's own name, which a profiler trace's op event starts with
@@ -77,15 +105,70 @@ def test_kernel_compiles_at_yi_9b_leaf(topo, one_chip, name, shape):
         assert re.search(rf"%guided_{name}_update(\.\d+)? = ", ln), ln
 
 
+@pytest.mark.parametrize("leaf", [(m, *x) for m, xs in CELL_LEAVES.items() for x in xs],
+                         ids=lambda x: f"{x[0]}-{'x'.join(map(str, x[1]))}")
+@pytest.mark.parametrize("name", ["sgd", "adam"])
+def test_derived_block_compiles_at_every_cell_leaf(topo, one_chip, monkeypatch, name, leaf):
+    """Each leaf of both cells, as its cell runs it (sgd at lambda = 0, adam
+    with DC-ASGD's term), compiles at its derived block: the leaf is read in
+    its own layout (no copy, reshape or transpose beside the kernel), a split
+    last dim is split evenly, and a grid step moves at least 2 MB where the
+    leaf is larger than one block."""
+    _, shape, dtype = leaf
+    seen = {}
+    stream_block, tiling = K.stream_block, K.tiling
+
+    def record_streams(dtypes):
+        seen["bytes"] = sum(jnp.dtype(d).itemsize for d in dtypes)
+        return stream_block(dtypes)
+
+    def record_tiling(shape, block):
+        seen["tiling"] = tiling(shape, block)
+        return seen["tiling"]
+
+    monkeypatch.setattr(K, "stream_block", record_streams)
+    monkeypatch.setattr(K, "tiling", record_tiling)
+    compiled = _compile(name, shape, jnp.dtype(dtype), None, one_chip,
+                        lam=0.0 if name == "sgd" else 0.04)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= \S+ (copy|reshape|transpose)\(", text)
+    view, bs, grid = seen["tiling"]
+    assert bs[-1] == shape[-1] or (shape[-1] % bs[-1] == 0 and bs[-1] % RK.LANE == 0)
+    step = int(np.prod(bs)) * seen["bytes"]
+    assert 2 * step <= RK.VMEM_STREAM_BYTES
+    if grid != (1, 1, 1):
+        assert step >= 2e6, (bs, step)
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("name", ["sgd", "momentum", "adam"])
-def test_every_allowed_autotune_block_compiles(topo, one_chip, name, dtype):
-    """The sweep may pick any of `candidates`; the compiler must take each
-    (a refused block is an error at the first train step, not a slow run)."""
-    allowed = autotune.candidates(f"guided_{name}_update", dtype)
-    assert autotune.DEFAULT_BLOCK in allowed
-    for block in allowed:
-        _compile(name, (4096, 11008), jnp.dtype(dtype), block, one_chip)
+@pytest.mark.parametrize("name", ["sgd", "momentum", "rmsprop", "adam"])
+def test_derived_block_compiles_for_every_kernel(topo, one_chip, name, dtype):
+    """Every kernel of the family at either weight dtype, with and without
+    its w_stale stream, takes its derived block, with merged rows and with
+    the last two dims kept."""
+    for shape in ((4096, 11008), (8, 512, 2, 5760)):
+        for lam in (0.0, 0.04):
+            _compile(name, shape, jnp.dtype(dtype), None, one_chip, lam)
+
+
+def test_lam_zero_kernel_has_no_w_stale_operand_and_the_reader_sees_it(topo, one_chip):
+    """At a static lambda = 0 the sgd kernel streams w and g only: two array
+    operands before its f32[2] scalar pack, and the benchmark's
+    `guided_update_ms` reader still takes the call for the update's; at
+    lambda = 0.04 it streams w_stale too."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "chip",
+                        "metrics", "guided_update_ms.py")
+    spec = importlib.util.spec_from_file_location("guided_update_ms", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    for lam, n_arrays in ((0.0, 2), (0.04, 3)):
+        compiled = _compile("sgd", (13, 4096, 2, 11008), jnp.bfloat16, None, one_chip, lam)
+        (line,) = _kernel_lines(compiled)
+        operands = line.split(" custom-call(")[1].split("), custom_call_target")[0]
+        shapes = re.findall(r"(?:^|, )(\w+\[[\d,]*\])", operands)
+        assert shapes == ["bf16[53248,2,11008]"] * n_arrays + ["f32[2]"], shapes
+        assert re.search(reader.KERNEL, line), line
 
 
 def test_fused_update_compiles_per_shard_on_2x2_mesh(topo, monkeypatch):
