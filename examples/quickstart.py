@@ -32,11 +32,13 @@ spec = ExperimentSpec(
 
 
 def on_step(step, m, params):
-    corr_w = float(m["corr_weight_sum"])
+    # one call per dispatch, metrics stacked over its steps: (1,) at the
+    # default chunk_steps=1
+    corr_w = float(m["corr_weight_sum"][0])
     if step % 5 == 0 or corr_w > 0:
         print(
-            f"step {step:3d} loss={float(m['loss']):.4f} "
-            f"worker_var={float(m['worker_loss_var']):.2e} "
+            f"step {step:3d} loss={float(m['loss'][0]):.4f} "
+            f"worker_var={float(m['worker_loss_var'][0]):.2e} "
             f"guided_correction={'FIRED' if corr_w > 0 else '-'}"
         )
 

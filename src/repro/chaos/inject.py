@@ -63,6 +63,16 @@ class ChaosPlan:
                 out[kind] = table
         return out or None
 
+    def holds(self) -> tuple:
+        """((wid, at_version, is_kill), ...): the live store holds the run at
+        each worker-targeted fault until it has landed (`ParameterStore._held`),
+        so it lands by construction, not by pace. A poisoned gradient lands
+        once the sentinel bans its sender: plant one with `spec.sentinel` and
+        `spec.quarantine_steps`."""
+        return tuple((w, v, kind == "kills") for kind in
+                     ("kills", "resets", "corrupt_frame", "nan_grad", "boom_grad")
+                     for w, v in _as_table(getattr(self, kind)).items())
+
     def kill_events(self) -> dict:
         return _as_table(self.kills)
 

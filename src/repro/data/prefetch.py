@@ -1,7 +1,7 @@
 """Async double-buffered host->device batch staging (`repro.data.prefetch`).
 
-The chunked mesh trainer (DESIGN.md §9) dispatches K fused train steps per
-jit call; this module keeps that dispatch fed. Two pieces:
+The mesh trainer (DESIGN.md §9) dispatches a chunk of K train steps per jit
+call; this module keeps that dispatch fed. Two pieces:
 
   * `stack_blocks` turns a per-step batch stream into pre-stacked `(K, ...)`
     numpy blocks following a chunk schedule. It is a plain generator, so the
@@ -15,8 +15,7 @@ jit call; this module keeps that dispatch fed. Two pieces:
     computes). Neither batch generation nor the H2D copy ever sits on the
     dispatch critical path.
 
-Both are backend-agnostic: the "blocks" are arbitrary pytrees, so the same
-prefetcher stages single per-step batches when `chunk_steps=1`.
+The prefetcher is backend-agnostic: the "blocks" are arbitrary pytrees.
 
 The worker's profiler spans: `prefetch.make` (drawing the next item from the
 source: batch generation and stacking; the last one finds the end of a finite
@@ -47,20 +46,20 @@ def stack_blocks(batches: Iterator[dict], sizes: Sequence[int]) -> Iterator[dict
             except StopIteration:
                 raise ValueError(
                     f"data stream exhausted mid-chunk (got {len(rows)} of {k} "
-                    f"batches); a chunked fit needs n_steps batches — pass a "
+                    f"batches); a fit needs n_steps batches — pass a "
                     f"long-enough stream or lower spec.steps") from None
         yield {key: np.stack([np.asarray(r[key]) for r in rows])
                for key in rows[0]}
 
 
-def batch_put(ctx, stacked: bool) -> Callable:
-    """Leaf-wise device placement for (stacked) batches on `ctx`.
+def batch_put(ctx) -> Callable:
+    """Leaf-wise device placement for stacked `(K, B, ...)` batch blocks on
+    `ctx`.
 
-    On a distributed ShardCtx the batch dimension — axis 1 of a stacked
-    `(K, B, ...)` block, axis 0 of a per-step batch — is committed against the
-    data axes, so the H2D transfer lands each worker's shard directly on its
-    devices; everything else replicates. On the local (meshless) ctx this is
-    a plain transfer, byte-identical to the `jnp.asarray` staging it replaces.
+    On a distributed ShardCtx the batch dimension, axis 1, is committed
+    against the data axes, so the H2D transfer lands each worker's shard
+    directly on its devices; everything else replicates. On the local
+    (meshless) ctx this is a plain transfer, byte-identical to `jnp.asarray`.
     """
     import jax
     import jax.numpy as jnp
@@ -70,14 +69,13 @@ def batch_put(ctx, stacked: bool) -> Callable:
 
     from jax.sharding import NamedSharding, PartitionSpec
 
-    bdim = 1 if stacked else 0
     axes = tuple(a for a in ctx.data_axes if a in ctx.mesh.shape)
     n_shards = int(np.prod([ctx.mesh.shape[a] for a in axes])) if axes else 1
 
     def one(x):
         spec = [None] * np.ndim(x)
-        if axes and np.ndim(x) > bdim and x.shape[bdim] % n_shards == 0:
-            spec[bdim] = axes if len(axes) > 1 else axes[0]
+        if axes and np.ndim(x) > 1 and x.shape[1] % n_shards == 0:
+            spec[1] = axes if len(axes) > 1 else axes[0]
         return jax.device_put(x, NamedSharding(ctx.mesh, PartitionSpec(*spec)))
 
     return lambda tree: jax.tree.map(one, tree)

@@ -150,6 +150,7 @@ class Chief:
                 store.record_bad_frame(wid, ValueError(f"expected hello, got {verb!r}"))
                 return
             wid = self._assign_wid(requested)
+            store.record_hello(wid)
             if self.leases is not None:
                 self.leases.touch(wid)
             conn.send(("welcome", wid, self.meta))

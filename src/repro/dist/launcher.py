@@ -155,12 +155,13 @@ def run_local(spec, X, y, n_classes: int, Xtest=None, ytest=None,
         if not (policy.screening or policy.rollback):
             policy = None
 
+    supervise = spawn and not replay and spec.dist_supervise
     store = ParameterStore(
         spec, strategy, W0, train, val, total_steps=T,
         schedule=schedule if replay else None,
         drop_rate=scenario.drop_rate, seed=spec.seed,
         checkpointer=checkpointer, ckpt_every=spec.ckpt_every,
-        policy=policy)
+        policy=policy, holds=chaos.holds() if chaos is not None and supervise else ())
 
     meta = {
         "Xtr": np.asarray(train[0], np.float64),
@@ -186,7 +187,6 @@ def run_local(spec, X, y, n_classes: int, Xtest=None, ytest=None,
         chaos_kills = dict(chaos.kill_events())
         truncate_at = chaos.truncate_at
 
-    supervise = spawn and not replay and spec.dist_supervise
     leases = LeaseTable(spec.dist_lease_s) \
         if supervise and spec.dist_lease_s else None
     chief = Chief(store, meta, port=port, leases=leases,

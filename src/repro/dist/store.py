@@ -27,7 +27,8 @@ Two grant disciplines share this apply path:
     tests/test_dist.py.
   * live — free-running. Pushes apply in arrival order at wall-clock speed;
     `drop_rate` injects dropped updates; late pushes after the step budget
-    are counted, not crashed on.
+    are counted, not crashed on. A `repro.chaos` plan's holds make each
+    planted fault land before the budget drains (`_held`).
 
 Thread safety: one lock/condition serializes applies (the parameter server
 is sequential by definition — the asynchrony lives between processes).
@@ -81,7 +82,8 @@ class ParameterStore:
 
     def __init__(self, spec, strategy, W0, train, val, total_steps: int,
                  schedule=None, drop_rate: float = 0.0, seed: int = 0,
-                 checkpointer=None, ckpt_every: int = 0, policy=None):
+                 checkpointer=None, ckpt_every: int = 0, policy=None,
+                 holds=()):
         self.spec = spec
         self.strategy = strategy
         self.W = np.asarray(W0, np.float64).copy()
@@ -128,6 +130,9 @@ class ParameterStore:
         # last committed sane state: the rollback target when no verified
         # on-disk snapshot exists (or the dir predates checksums)
         self._good = (self.W.copy(), self.r.copy())
+        self._holds = list(holds)       # ChaosPlan.holds, until landed
+        self._seen: set = set()         # wids that have said hello
+        self._back: dict = {}           # wid -> version it last reconnected at
         # ---- concurrency
         self.cond = threading.Condition()
         self._drop_rng = np.random.default_rng(seed + 7919)
@@ -316,6 +321,19 @@ class ParameterStore:
             self.resets += 1
             self.cond.notify_all()
 
+    def _held(self, wid) -> bool:
+        """Whether `wid`'s step waits (caller holds the lock): the earliest
+        planted fault whose version has come has not landed (its target is
+        neither back nor banned), and is another worker's or a kill (the
+        launcher makes it, the supervisor brings the target back)."""
+        banned = lambda w: self.screen is not None and \
+            self.version < self.screen.quarantined_until.get(w, -1)
+        self._holds = [h for h in self._holds
+                       if not (self._back.get(h[0], -1) >= h[1] or banned(h[0]))]
+        due = sorted((at, w, kill) for w, at, kill in self._holds if self.version >= at)
+        return (bool(due) and self.fatal is None and self.version < self.total
+                and (due[0][2] or due[0][1] != wid))
+
     def fatal_error(self):
         with self.cond:
             return self.fatal
@@ -394,6 +412,7 @@ class ParameterStore:
         until its ban lifts, but it still receives fresh params — it may
         recover (a transient NaN source) without a respawn."""
         with self.cond:
+            self.cond.wait_for(lambda: not self._held(wid))
             if self.fatal is not None:
                 return None
             if g is not None:
@@ -402,7 +421,9 @@ class ParameterStore:
                     self.late += 1
                 elif self.screen is not None and \
                         self.screen.admit(wid, g, self.version) is not None:
-                    pass     # rejected/quarantined: counted by the screen
+                    # rejected/quarantined: counted by the screen; a ban
+                    # lands a hold (`_held`)
+                    self.cond.notify_all()
                 elif self.drop_rate and self._drop_rng.random() < self.drop_rate:
                     self.drops += 1
                 else:
@@ -417,6 +438,14 @@ class ParameterStore:
         """An elastic worker joined (chief assigned it a fresh wid)."""
         with self.cond:
             self.joins += 1
+
+    def record_hello(self, wid):
+        """A worker connected as `wid`; again: a fault of it has landed."""
+        with self.cond:
+            if wid in self._seen:
+                self._back[wid] = self.version
+            self._seen.add(wid)
+            self.cond.notify_all()
 
     def record_worker_exit(self):
         """A worker connection died mid-stream (kill/crash): tolerated,

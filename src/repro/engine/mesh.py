@@ -1,8 +1,7 @@
 """The strategy-driven SPMD train step (mesh backend of `repro.engine`).
 
-This is the single train-step implementation both the legacy
-`repro.train.steps.build_train_step` shim and `Trainer` dispatch to. The
-paper's technique meets the mesh here (DESIGN.md §3):
+This is the single train-step implementation; `Trainer` dispatches it in
+chunks (`repro.engine.trainloop`). The paper's technique meets the mesh here (DESIGN.md §3):
 
   * per-worker losses E_i come free from the per-example loss vector (each
     data shard of the batch is one of the paper's c workers);

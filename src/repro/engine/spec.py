@@ -167,8 +167,8 @@ class ExperimentSpec:
                                    # (dist: worker PROCESSES; 0 -> schedule's c)
     micro: int = 1                 # gradient-accumulation microbatches
     staleness: int = 0             # asgd: w_stale refresh period (0 -> rho)
-    chunk_steps: int = 1           # fuse K steps into one lax.scan dispatch
-                                   # (1 -> the literal per-step legacy loop)
+    chunk_steps: int = 1           # train steps per dispatch: one compiled
+                                   # loop over K steps (bit-exact for every K)
     prefetch: bool = False         # async double-buffered batch staging
     dc_lambda: float = 0.04
     correction_scale: float = 1.0
@@ -221,8 +221,8 @@ class ExperimentSpec:
                 f"the snapshots go?)")
         if self.chunk_steps < 1:
             raise ValueError(
-                f"chunk_steps must be >= 1 (got {self.chunk_steps}); 1 runs "
-                f"the per-step loop, K > 1 fuses K steps per dispatch")
+                f"chunk_steps must be >= 1 (got {self.chunk_steps}): the "
+                f"train steps each dispatch runs")
         # strategy/mode compatibility fails here, at construction, with the
         # registry's message — not deep inside jit or mid-fit.
         why = _STALE_REQUIRED.get(self.strategy)

@@ -309,7 +309,8 @@ def get_compensator(name: str, gcfg: G.GuidedConfig) -> DelayCompensator:
 
 
 def strategy_name_for(gcfg: G.GuidedConfig) -> str:
-    """Legacy GuidedConfig -> registry name (the shim `train.steps` uses)."""
+    """Legacy GuidedConfig -> registry name (what `engine.mesh` runs when no
+    strategy is named)."""
     if gcfg.mode == "dc_asgd":
         return "dc_asgd_guided" if gcfg.guided else "dc_asgd"
     if gcfg.guided:

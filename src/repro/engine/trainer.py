@@ -13,9 +13,10 @@
     report = Trainer.from_spec(spec).fit()          # synthetic LM stream
     report.final_loss, report.history
 
-The mesh path jits the strategy-driven step from `repro.engine.mesh` and is
-numerically identical, step for step, to the legacy
-`train.steps.build_train_step` loop (tests/test_engine.py locks this in).
+The mesh path runs the strategy-driven step from `repro.engine.mesh` in
+chunks (`repro.engine.trainloop`) and is numerically identical, step for
+step, to an independent loop over that step (tests/test_engine.py locks this
+in).
 The sim path drives the literal numpy parameter server; the scan path drives
 the jitted `repro.engine.delaysim` simulator, which reproduces the sim's
 trajectories to float64 round-off (tests/test_delaysim.py). Either way the
@@ -127,35 +128,27 @@ class Trainer:
         sim backend: `data` is (X, y, n_classes[, Xtest, ytest]).
         mesh backend: `data` is an iterable of batch dicts (or None for the
         synthetic LM stream); `steps` overrides spec.steps; `on_step(step,
-        metrics, params)` fires after every step with the RAW device metrics
-        dict (loss, worker_loss_var, corr_weight_sum, lr, step) — reading a
-        value forces a host sync, so cheap callbacks only touch them on their
-        own logging cadence. The `params` handed to on_step are donated to the
-        next step's jit call — read or save them synchronously inside the
-        callback; retaining them across steps raises "Array has been deleted".
-        Report.history is materialized after the loop so the hot path never
-        blocks on device->host transfers; long launcher runs that keep their
-        own log-step records pass keep_history=False to retain (and sync)
-        only the final step.
-
-        Pipelining (mesh backend, DESIGN.md §9): spec.chunk_steps=K > 1 fuses
-        K train steps into one jitted lax.scan dispatch over a stacked
-        (K, ...) batch block — bit-exact with the per-step loop, but on_step
-        then fires once per CHUNK with stacked (k,) device metrics and
-        step = the last step index of the chunk (chunk_steps=1 restores the
-        legacy per-step scalar contract, and runs the literal legacy loop).
-        spec.prefetch=True stages the next chunk's batches (generation,
-        stacking, device_put against the data-shard sharding) on a background
-        thread while the current chunk computes. Checkpoint cadence is
-        preserved exactly: chunks split at ckpt_every multiples, and SIGTERM
-        drains the in-flight chunk before the final snapshot.
+        metrics, params)` fires after every dispatch — a chunk of k <=
+        spec.chunk_steps steps (DESIGN.md §9) — with the RAW device metrics
+        dict (loss, worker_loss_var, corr_weight_sum, lr, step) stacked to
+        (k,), and step = the chunk's last step index. Reading a value forces
+        a host sync, so cheap callbacks only touch them on their own logging
+        cadence. The `params` handed to on_step are donated to the next
+        dispatch — read or save them synchronously inside the callback;
+        retaining them raises "Array has been deleted". Report.history is
+        materialized after the loop so the hot path never blocks on
+        device->host transfers; long launcher runs that keep their own
+        log-step records pass keep_history=False to retain (and sync) only
+        the final step. Any chunk_steps gives the same run bit for bit;
+        spec.prefetch=True stages the next chunk's batches on a background
+        thread while the current chunk computes.
 
         Checkpointing (mesh backend, DESIGN.md §8): spec.ckpt_dir enables
         full-state snapshots — params AND GuidedState (opt state, consistency
         scores, w_stale ring, strategy extra, step) plus the data-stream
         cursor — written asynchronously every spec.ckpt_every steps and once
         at loop exit (SIGTERM included: the handler finishes the in-flight
-        step, snapshots, and returns with Report.interrupted=True).
+        chunk, snapshots, and returns with Report.interrupted=True).
         resume=True restarts from the latest manifest entry in spec.ckpt_dir
         bit-exactly: train(N) == train(k) + resume(N-k), leaf for leaf (a
         missing/empty ckpt_dir starts fresh). When resuming with an explicit
@@ -245,8 +238,3 @@ class Trainer:
         return trainloop.fit(self.spec, self.strategy, data=data, steps=steps,
                              on_step=on_step, keep_history=keep_history,
                              resume=resume)
-
-    def _synthetic_batches(self, cfg, c: int):
-        from repro.engine.trainloop import synthetic_stream
-
-        return synthetic_stream(self.spec, cfg, c)

@@ -1,40 +1,37 @@
-"""Pipelined mesh fit loop: chunked multi-step dispatch + prefetch (DESIGN.md §9).
+"""Pipelined mesh fit loop: multi-step chunk dispatch + prefetch (DESIGN.md §9).
 
-The per-step loop the Trainer used to run — one Python-dispatched jit call per
-train step with a synchronous `jnp.asarray` host->device copy in front of it —
-leaves the accelerator idle on dispatch and data staging whenever per-step
-compute is small. This module is the same treatment PR 2 gave the delay
-simulator, applied to real mesh training:
+`fit` runs one compiled program, the chunk: K train steps in a loop over a
+stacked `(K, ...)` batch block, with the `(params, gstate)` carry donated
+end-to-end and the metrics stacked to `(K,)` arrays on device, so the host
+dispatches and syncs once per chunk instead of once per step.
 
   * `chunk_schedule` partitions the step range into dispatch chunks of at most
     `spec.chunk_steps` steps, split (never shifted) so every `ckpt_every`
     multiple lands on a chunk boundary — the snapshot cadence is preserved
     exactly, and a resume point may land anywhere in the schedule;
-  * `build_chunk_step` fuses K train steps into ONE jitted `lax.scan` over a
-    stacked `(K, ...)` batch block with the `(params, gstate)` carry donated
-    end-to-end; metrics accumulate on device and come back as stacked `(K,)`
-    arrays, so per-step history is preserved while the host syncs once per
-    chunk instead of once per step;
+  * `build_chunk_step` builds the chunk. Every size, one included, compiles
+    the step as a loop body, which is what makes any partition of a run into
+    chunks follow the same trajectory;
   * the `repro.data.prefetch` double buffer stages block i+1 (batch
     generation, stacking, and the `jax.device_put` against the data-shard
     sharding) on a worker thread while chunk i computes.
 
 Contracts (locked in tests/test_trainloop.py):
 
-  * bit-exactness — chunked+prefetched fit(N) == the stepwise loop
-    leaf-for-leaf (params, gstate, and per-step history) for every registered
-    strategy; `chunk_steps=1` runs the literal legacy per-step loop;
-  * checkpoints land on exactly the same steps as the stepwise loop, and
+  * partition invariance — fit(N) is the same leaf for leaf (params, gstate,
+    and per-step history) whatever `chunk_steps` and `prefetch` are, for
+    every registered strategy;
+  * checkpoints land on exactly the steps of the `ckpt_every` cadence, and
     resume is bit-exact from any snapshot, including resume points between
     the natural chunk boundaries (the schedule is recomputed from
-    `start_step`, and any chunk partition yields the same trajectory);
+    `start_step`);
   * SIGTERM drains the in-flight chunk, snapshots the full state at its
     boundary, and returns `Report.interrupted=True`;
   * `on_step(step, metrics, params)` fires once per chunk with the stacked
-    `(k,)` device metrics and `step` = the LAST step index of the chunk;
-    `chunk_steps=1` restores the legacy per-step scalar contract. Either way
-    the `params` handed over are donated to the next dispatch — read or save
-    them synchronously inside the callback.
+    `(k,)` device metrics and `step` = the LAST step index of the chunk (at
+    `chunk_steps=1`, once per step with `(1,)` metrics). The `params` handed
+    over are donated to the next dispatch — read or save them synchronously
+    inside the callback.
 
 Profiler spans (`jax.profiler`; no-ops when no trace is being recorded):
 `fit.input_wait` (taking the next staged block), `fit.dispatch` (the host
@@ -58,8 +55,8 @@ def chunk_schedule(start: int, stop: int, chunk_steps: int,
 
     Each chunk is at most `chunk_steps` long; when `ckpt_every` is set, every
     multiple of it lands on a chunk boundary (chunks are split at the cadence,
-    never shifted past it), so the chunked loop snapshots at exactly the steps
-    the stepwise loop would. A `start` mid-cadence (resume from a snapshot
+    never shifted past it), so snapshots land on exactly the cadence's steps
+    whatever `chunk_steps` is. A `start` mid-cadence (resume from a snapshot
     that a split chunk produced) re-aligns at the next multiple.
     """
     if chunk_steps < 1:
@@ -82,45 +79,66 @@ _METRIC_KEYS = (("loss", "loss"), ("worker_var", "worker_loss_var"),
 
 def step_records(m, first: int, indices=None) -> List[dict]:
     """Materialize per-step history records from ONE dispatch's raw device
-    metrics — scalar per-step values (`chunk_steps=1`) or stacked `(k,)`
-    chunk arrays. `first` is the step index of the dispatch's first step;
-    `indices` restricts which in-chunk offsets materialize (None -> all).
+    metrics, stacked `(k,)` arrays. `first` is the step index of the
+    dispatch's first step; `indices` restricts which in-chunk offsets materialize (None -> all).
     The single host transfer per metric happens here, so callers on a
     logging cadence (the launcher) pass only their log offsets and an empty
     selection never syncs at all.
     """
     import jax
 
-    shape = getattr(m["loss"], "shape", ())
-    if indices is None:
-        indices = range(shape[0] if shape else 1)
-    indices = list(indices)
+    indices = list(range(m["loss"].shape[0]) if indices is None else indices)
     if not indices:
         return []
     # ONE batched host transfer for all metrics of the dispatch
     vals = jax.device_get(tuple(m[key] for _, key in _METRIC_KEYS))  # lint: allow[host-sync-in-hot-loop] the single per-dispatch sync point
     arrs = dict(zip((name for name, _ in _METRIC_KEYS), vals))
     return [{"step": first + i,
-             **{name: float(a[i] if shape else a) for name, a in arrs.items()}}  # lint: allow[host-sync-in-hot-loop] host np scalars after the batched get
+             **{name: float(a[i]) for name, a in arrs.items()}}  # lint: allow[host-sync-in-hot-loop] host np scalars after the batched get
             for i in indices]
 
 
 def build_chunk_step(step_fn: Callable) -> Callable:
     """Fuse `step_fn(params, gstate, batch) -> (params, gstate, metrics)` into
-    `chunk_fn(params, gstate, stacked)`: one `lax.scan` over the leading axis
-    of `stacked` (a `(K, ...)`-stacked batch block) with the train state as
-    the carry. Returns the final state plus metrics stacked to `(K,)` arrays.
-    Jit it with `donate_argnums=(0, 1)` — the carry is donated end-to-end.
+    `chunk_fn(params, gstate, stacked)`: one compiled loop over the leading
+    axis of `stacked` (a `(K, ...)`-stacked batch block) with the train state
+    as the carry. Returns the final state plus metrics stacked to `(K,)`
+    arrays. Jit it with `donate_argnums=(0, 1)` — the carry is donated
+    end-to-end.
     """
     import jax
+    import jax.numpy as jnp
 
     def chunk_fn(params, gstate, stacked):
         def body(carry, batch):
             p, g, m = step_fn(carry[0], carry[1], batch)
             return (p, g), m
 
-        (params, gstate), metrics = jax.lax.scan(body, (params, gstate), stacked)
-        return params, gstate, metrics
+        if jax.tree.leaves(stacked)[0].shape[0] > 1:
+            (params, gstate), metrics = jax.lax.scan(body, (params, gstate), stacked)
+            return params, gstate, metrics
+
+        # K = 1: XLA inlines a scan it can count to one, and folds an index
+        # into an axis of one, hoisting the batch's ops out of the loop; either
+        # way the step compiles to other roundings. So a chunk of one runs the
+        # scan's loop over a block of two, with the trip count of one hidden.
+        pair = jax.tree.map(lambda x: jnp.concatenate([x, x]), stacked)
+
+        def loop(i, carry):
+            batch = jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, i, keepdims=False), pair)
+            (p, g), m = body(carry[:2], batch)
+            ms = jax.tree.map(
+                lambda a, x: jax.lax.dynamic_update_index_in_dim(a, x, i, 0),
+                carry[2], m)
+            return p, g, ms
+
+        shapes = jax.eval_shape(body, (params, gstate),
+                                jax.tree.map(lambda x: x[0], stacked))[1]
+        ms = jax.tree.map(lambda s: jnp.zeros((2, *s.shape), s.dtype), shapes)
+        trips = jax.lax.optimization_barrier(jnp.int32(1))
+        params, gstate, ms = jax.lax.fori_loop(0, trips, loop, (params, gstate, ms))
+        return params, gstate, jax.tree.map(lambda a: a[:1], ms)
 
     return chunk_fn
 
@@ -146,9 +164,9 @@ def synthetic_stream(spec: ExperimentSpec, cfg, c: int):
 
 def build_dispatch(spec: ExperimentSpec, strategy, cfg, ctx, c: int,
                    n_steps: int):
-    """The jitted program `fit` dispatches: the strategy-driven train step
-    (sentinel-guarded when `spec.sentinel`), fused `spec.chunk_steps` steps
-    per call when that is > 1, with the `(params, gstate)` carry donated.
+    """The jitted program `fit` dispatches: the chunk (`build_chunk_step`) of
+    the strategy-driven train step (sentinel-guarded when `spec.sentinel`),
+    with the `(params, gstate)` carry donated.
     Lowering it on the train state's shapes compiles the step without running
     it (`chip_smoke.py` reads the compiled text and memory from there)."""
     import jax
@@ -166,14 +184,13 @@ def build_dispatch(spec: ExperimentSpec, strategy, cfg, ctx, c: int,
     if spec.sentinel:
         # divergence sentinel (DESIGN.md §14): screen every step ON DEVICE —
         # a rejected step keeps the previous (params, gstate) carry, so one
-        # NaN batch costs a step of progress, never the run; the scan/jit
-        # fusion is unchanged because the guard is part of step_fn itself
+        # NaN batch costs a step of progress, never the run; the chunk is
+        # unchanged because the guard is part of step_fn itself
         from repro.resilience import wrap_step_sentinel
 
         step_fn = wrap_step_sentinel(step_fn, spec.sentinel,
                                      spec.sentinel_factor)
-    return jax.jit(build_chunk_step(step_fn) if spec.chunk_steps > 1 else step_fn,
-                   donate_argnums=(0, 1))
+    return jax.jit(build_chunk_step(step_fn), donate_argnums=(0, 1))
 
 
 def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
@@ -229,7 +246,6 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
         params, gstate = jax.device_put((params, gstate),
                                         (sh["params"], sh["gstate"]))
     dispatch = build_dispatch(spec, strategy, cfg, ctx, c, n_steps)
-    chunked = spec.chunk_steps > 1
 
     start_step = 0
     if resume:
@@ -274,15 +290,13 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
         next(batches)            # so resumed steps see the exact batches
 
     sizes = chunk_schedule(start_step, n_steps, spec.chunk_steps, spec.ckpt_every)
-    # host-side source: pre-stacked (K, ...) blocks for the chunked path
-    # (generation + stacking run wherever the source is consumed — on the
-    # prefetch thread when spec.prefetch), per-step dicts otherwise
-    source = stack_blocks(batches, sizes) if chunked else batches
-    put = batch_put(ctx, stacked=chunked)
-    prefetcher = None
-    if spec.prefetch:
-        prefetcher = ChunkPrefetcher(source, put=put)
-        source = prefetcher
+    # stacked (K, ...) blocks staged by batch_put (sharded H2D placement on
+    # distributed meshes): generation, stacking and the copy run wherever
+    # the source is consumed — on the prefetch thread when spec.prefetch
+    put = batch_put(ctx)
+    source = stack_blocks(batches, sizes)
+    prefetcher = ChunkPrefetcher(source, put=put) if spec.prefetch else None
+    source = prefetcher if prefetcher is not None else map(put, source)
 
     # SIGTERM-safe: a preempted run drains the in-flight chunk, snapshots
     # full state, and exits cleanly instead of losing the window
@@ -300,9 +314,9 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
         except (ValueError, AttributeError):  # non-main interpreter / platform
             installed = False
 
-    raw = []                   # (first_step, k, metrics) per dispatch
+    raw = []                   # (first_step, metrics) per kept dispatch
     m = None
-    rej = None                 # device-side rejected-step accumulator
+    rej = 0                    # device-side rejected-step accumulator
     done = start_step
     compile_time_s = 0.0
     compiled_steps = 0         # steps covered by compiling dispatches
@@ -311,10 +325,8 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
     t_loop = time.perf_counter()   # the loop span: setup/restore excluded
     try:
         for k in sizes:
-            # staging always goes through batch_put: sharded H2D placement on
-            # distributed meshes, plain jnp.asarray-equivalent on local
             with TraceAnnotation("fit.input_wait"):
-                block = next(source) if spec.prefetch else put(next(source))
+                block = next(source)
             # every FIRST dispatch of a chunk shape jit-compiles (the uneven
             # tail and ckpt_every-split chunks each get their own program);
             # timing those (one host sync each) is what lets Report split
@@ -337,10 +349,10 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
             if spec.sentinel:
                 # stays device-side (async jnp add): ONE host read after the
                 # loop, not a sync per dispatch
-                r = m["rejected"].sum() if chunked else m["rejected"]
-                rej = r if rej is None else rej + r
-            if keep_history:
-                raw.append((done - k, k, m))
+                rej = rej + m["rejected"].sum()
+            if not keep_history:
+                raw.clear()
+            raw.append((done - k, m))
             if on_step is not None:
                 with TraceAnnotation("fit.on_step"):
                     on_step(done - 1, m, params)
@@ -386,12 +398,8 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
                 # noise; surface the writer error only on a clean loop
                 if not loop_failed:
                     raise
-    if not keep_history and m is not None:
-        last_k = jax.tree.leaves(m)[0].shape[0] if chunked else 1
-        raw = [(done - last_k, last_k, m)]
-
     history = []
-    for first, _, mi in raw:
+    for first, mi in raw:
         history.extend(step_records(mi, first))
     if not keep_history:
         history = history[-1:]
@@ -399,8 +407,7 @@ def fit(spec: ExperimentSpec, strategy, data=None, steps: Optional[int] = None,
     resilience = {}
     if spec.sentinel:
         resilience = {"sentinel": spec.sentinel,
-                      "rejected_steps": int(jax.device_get(rej))
-                      if rej is not None else 0}
+                      "rejected_steps": int(jax.device_get(rej))}
     return Report(backend="mesh", spec=spec, history=history, final=final,
                   model=params, state=gstate, n_steps=done - start_step,
                   start_step=start_step, interrupted=stop["sig"] is not None,

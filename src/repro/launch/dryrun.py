@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.configs import INPUT_SHAPES, ARCH_IDS, get_config
 from repro.core.guided import GuidedConfig
+from repro.engine import mesh as M
 from repro.launch.mesh import make_production_mesh, make_small_mesh
 from repro.models import transformer as T
 from repro.models.module import split_params
@@ -236,7 +237,7 @@ def lower_train(cfg, ctx, gcfg, opt_name, n_micro: int = 1):
         lambda ps: guided_init(gcfg, ps, opt, ctx.n_workers), params_struct
     )
     g_sh = S.state_shardings(gcfg, opt, p_sh, ctx.mesh)
-    step = S.build_train_step(cfg, gcfg, opt, ctx, constant(1e-2), n_micro=n_micro)
+    step = M.build_train_step(cfg, gcfg, opt, ctx, constant(1e-2), n_micro=n_micro)
     return step, (params_struct, p_sh), (gstate_struct, g_sh)
 
 

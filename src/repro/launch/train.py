@@ -195,9 +195,9 @@ def main(argv=None):
     ap.add_argument("--workers", type=int, default=0, help="logical worker count c (local mesh)")
     ap.add_argument("--micro", type=int, default=1)
     ap.add_argument("--chunk-steps", type=int, default=1,
-                    help="fuse K train steps into ONE jitted lax.scan dispatch "
-                         "(bit-exact with K=1; big win when per-step compute "
-                         "is small — see BENCH_train.json)")
+                    help="train steps per dispatch: one compiled loop over K "
+                         "steps (bit-exact for every K; big win when per-step "
+                         "compute is small — see BENCH_train.json)")
     ap.add_argument("--prefetch", action="store_true",
                     help="double-buffered async host->device batch staging "
                          "(overlaps generation + H2D with the in-flight chunk)")
@@ -276,14 +276,13 @@ def main(argv=None):
     t0 = time.time()
 
     def on_step(step, m, params):
-        # m holds raw device metrics — per-step scalars (chunk_steps=1) or
-        # stacked (k,) chunk arrays with step = the chunk's LAST step index;
-        # step_records only forces the host sync when a log step falls
-        # inside the window (empty selection -> no transfer)
+        # m holds the chunk's raw device metrics, stacked (k,), with step =
+        # the chunk's LAST step index; step_records only forces the host
+        # sync when a log step falls inside the chunk (empty selection -> no
+        # transfer)
         from repro.engine.trainloop import step_records
 
-        shape = getattr(m["loss"], "shape", ())
-        k = shape[0] if shape else 1
+        k = m["loss"].shape[0]
         first = step - k + 1
         logged = [i for i in range(k)
                   if (first + i) % args.log_every == 0 or first + i == args.steps - 1]

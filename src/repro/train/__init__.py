@@ -1,9 +1,6 @@
 from repro.train.steps import (  # noqa: F401
-    TrainFns,
     batch_shardings,
     build_decode_step,
     build_prefill_step,
-    build_train_step,
-    make_train_state,
     state_shardings,
 )

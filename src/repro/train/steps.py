@@ -1,29 +1,17 @@
-"""jit-able train / prefill / decode steps with guided delay compensation.
+"""jit-able prefill / decode steps and the sharding-tree helpers.
 
-The train-step implementation now lives in `repro.engine.mesh`, driven by the
-pluggable `DelayCompensator` strategies of `repro.engine.strategies`
-(DESIGN.md §2-3). `build_train_step` / `make_train_state` here are kept as
-thin deprecated shims over that engine — new code should go through
-`repro.engine.Trainer` / `repro.engine.build_train_step` directly. The
-serve-side prefill/decode step builders and the sharding-tree helpers remain
-canonical in this module.
+The train step lives in `repro.engine.mesh` (`init_train_state`,
+`build_train_step`), driven by the pluggable `DelayCompensator` strategies of
+`repro.engine.strategies` (DESIGN.md §2-3).
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
-
 import jax
-import jax.numpy as jnp
 
 from repro.core import guided as G
 from repro.models import transformer as T
 from repro.optim import Optimizer
 from repro.sharding.rules import ShardCtx, logical_to_spec
-
-
-class TrainFns(NamedTuple):
-    train_step: Callable
-    init_fn: Callable
 
 
 # ------------------------------------------------------------- shardings
@@ -98,28 +86,6 @@ def cache_shardings(cfg, ctx: ShardCtx, cache_struct):
 
     return jax.tree.map(one, logical, cache_struct,
                         is_leaf=lambda x: isinstance(x, tuple) and all(isinstance(e, (str, type(None))) for e in x))
-
-
-# --------------------------------------------------------------- train step
-
-
-def make_train_state(key, cfg, gcfg: G.GuidedConfig, opt: Optimizer, n_workers: int):
-    """Deprecated shim over repro.engine.init_train_state (same signature)."""
-    from repro.engine import mesh as _engine
-
-    return _engine.init_train_state(key, cfg, gcfg, opt, n_workers)
-
-
-def build_train_step(cfg, gcfg: G.GuidedConfig, opt: Optimizer, ctx: ShardCtx, lr_schedule,
-                     n_micro: int = 1, n_workers: int = 0):
-    """Deprecated shim over repro.engine.build_train_step: derives the
-    DelayCompensator strategy the GuidedConfig flags imply and delegates.
-    New code should use repro.engine.Trainer / repro.engine.build_train_step,
-    which also accept a strategy by registry name or instance."""
-    from repro.engine import mesh as _engine
-
-    return _engine.build_train_step(cfg, gcfg, opt, ctx, lr_schedule,
-                                    n_micro=n_micro, n_workers=n_workers)
 
 
 # --------------------------------------------------------------- serve steps

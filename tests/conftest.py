@@ -8,6 +8,10 @@ import jax
 import pytest
 
 jax.config.update("jax_enable_x64", False)
+# the launchers turn JAX's persistent compilation cache on for their process
+# (repro.common.cache); under pytest that would be every later test of the
+# worker, compiled into and read back from the checkout's .cache/
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(scope="session", autouse=True)

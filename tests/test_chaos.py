@@ -5,7 +5,8 @@ Every test runs a real 2-process live parameter-server fit under one seeded
 budget, trains to tolerance of the no-fault baseline, and `Report.dist`
 records the remediation that did it (respawns, rejections, quarantines,
 rollbacks, reset/bad-frame counts). Thresholds are store versions, not wall
-times, so every plan fires deterministically mid-run.
+times, and the store holds the other workers at each one until its fault has
+landed, so every plan fires deterministically mid-run.
 
 Kept deliberately small (18 server steps per run) so the whole module stays
 well under the 90s chaos-gate budget on a loaded CI box.
@@ -27,9 +28,9 @@ def _toy(n=120, d=5, seed=0):
     y = (X @ w > 0).astype(np.int64)
     return X, y, 2
 
-# dist_time_scale paces worker compute (~10ms/step) so version thresholds in
-# the middle of the 18-step budget fire before the run drains — same trick
-# as test_dist's kill/restart test
+# every planted fault lands by construction: from its version on, the store
+# holds the other workers until it has landed and been remediated
+# (ChaosPlan.holds); dist_time_scale only paces worker compute (~10ms/step)
 COMMON = dict(backend="dist", dist_mode="live", mode="asgd", strategy="none",
               epochs=3, batch_size=16, rho=2, lr=0.2, seed=0, workers=2,
               dist_time_scale=0.01, dist_timeout=60.0)
@@ -77,9 +78,8 @@ def test_sigkill_mid_run_respawns_and_completes():
 
 
 def test_connection_reset_recovers():
-    # paced 3x slower than the other tests: the reset fires early and the
-    # remaining budget must outlast death-detection + respawn backoff, so the
-    # respawn demonstrably lands INSIDE the run
+    # paced 3x slower than the other tests; the hold at the reset's version
+    # keeps the respawn INSIDE the run whatever the pace
     spec = ExperimentSpec(**COMMON).replace(dist_time_scale=0.03)
     res = _run(spec, ChaosPlan(resets=((0, 4),)))
     _assert_healed(res)

@@ -74,7 +74,7 @@ SCRIPT = textwrap.dedent(
     # --------- distributed guided train step == local train step --------
     from repro.core.guided import GuidedConfig
     from repro.optim import constant, get_optimizer
-    from repro.train import steps as STEPS
+    from repro.engine import mesh as M
     from repro.data import make_batch_for
 
     cfg3 = get_config("yi_9b").reduced()
@@ -85,8 +85,8 @@ SCRIPT = textwrap.dedent(
 
     losses = {}
     for name, ctx3 in (("local", LOCAL_CTX), ("mesh", ShardCtx(mesh=mesh, rules=DEFAULT_RULES))):
-        p3, _, g3 = STEPS.make_train_state(jax.random.PRNGKey(0), cfg3, gcfg, opt, n_workers=4)
-        step = jax.jit(STEPS.build_train_step(cfg3, gcfg, opt, ctx3, constant(1e-2), n_workers=4))
+        p3, _, g3 = M.init_train_state(jax.random.PRNGKey(0), cfg3, gcfg, opt, n_workers=4)
+        step = jax.jit(M.build_train_step(cfg3, gcfg, opt, ctx3, constant(1e-2), n_workers=4))
         ls = []
         for _ in range(4):
             p3, g3, m = step(p3, g3, batch)

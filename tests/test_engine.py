@@ -1,6 +1,6 @@
 """Tests for the unified repro.engine API: spec round-trips, the
 DelayCompensator registry, and step-for-step parity of the Trainer mesh path
-with the legacy build_train_step loop."""
+with an independent loop over engine.mesh.build_train_step."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -204,17 +204,22 @@ def test_custom_strategy_with_array_extra_state():
 
 
 def _legacy_losses(cfg, gcfg, n_steps, batches):
+    """An independent loop over `engine.mesh`'s step: its own init, the
+    strategy the GuidedConfig flags imply, a constant lr, and one dispatch of
+    the chunk program per batch (a block of one)."""
+    from repro.engine import mesh as M
+    from repro.engine.trainloop import build_chunk_step
     from repro.optim import constant, get_optimizer
-    from repro.train import steps as S
     from repro.sharding.rules import LOCAL_CTX
 
     opt = get_optimizer("sgd")
-    params, _, gstate = S.make_train_state(jax.random.PRNGKey(3), cfg, gcfg, opt, n_workers=2)
-    step = jax.jit(S.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2))
+    params, _, gstate = M.init_train_state(jax.random.PRNGKey(3), cfg, gcfg, opt, n_workers=2)
+    step = jax.jit(build_chunk_step(
+        M.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2)))
     losses = []
     for b in batches:
-        params, gstate, m = step(params, gstate, b)
-        losses.append(float(m["loss"]))
+        params, gstate, m = step(params, gstate, jax.tree.map(lambda x: x[None], b))
+        losses.append(float(m["loss"][0]))
     return losses
 
 
@@ -224,7 +229,8 @@ def _legacy_losses(cfg, gcfg, n_steps, batches):
     ("dc_asgd_guided", "asgd"),
 ])
 def test_trainer_matches_legacy_step_for_step(strategy, mode):
-    """Trainer.from_spec on the mesh path reproduces build_train_step losses."""
+    """Trainer.from_spec on the mesh path reproduces the losses of an
+    independent loop over engine.mesh.build_train_step."""
     from repro.data import make_batch_for
 
     spec = ExperimentSpec(

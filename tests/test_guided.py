@@ -179,15 +179,15 @@ def test_fused_correction_equals_manual_weighted_gradient():
 def test_train_step_guided_correction_fires_at_window_end():
     from repro.configs import get_config
     from repro.optim import constant, get_optimizer
-    from repro.train import steps as S
     from repro.data import make_batch_for
+    from repro.engine import mesh as M
     from repro.sharding.rules import LOCAL_CTX
 
     cfg = get_config("yi_9b").reduced()
     gcfg = G.GuidedConfig(mode="ssgd", guided=True, rho=3)
     opt = get_optimizer("sgd")
-    params, _, gstate = S.make_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
-    step = jax.jit(S.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2))
+    params, _, gstate = M.init_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
+    step = jax.jit(M.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2))
     corr = []
     batch = {k: jnp.asarray(v) for k, v in make_batch_for(cfg, 16, 4, seed=0).items()}
     for i in range(7):

@@ -36,13 +36,13 @@ def test_smoke_forward_train(arch):
 def test_smoke_train_step_decreases_loss(arch):
     from repro.core.guided import GuidedConfig
     from repro.optim import constant, get_optimizer
-    from repro.train import steps as S
+    from repro.engine import mesh as M
 
     cfg = get_config(arch).reduced()
     gcfg = GuidedConfig(mode="ssgd", guided=True, rho=3)
     opt = get_optimizer("sgd")
-    params, _, gstate = S.make_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
-    step = jax.jit(S.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2))
+    params, _, gstate = M.init_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
+    step = jax.jit(M.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(1e-2), n_workers=2))
     batch = {k: jnp.asarray(v) for k, v in make_batch_for(cfg, 32, 4, seed=0).items()}
     losses = []
     for _ in range(5):

@@ -382,6 +382,9 @@ class _StubStore:
     def record_join(self):
         pass
 
+    def record_hello(self, wid):
+        pass
+
     def progress(self):
         return 0
 

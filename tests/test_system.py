@@ -13,7 +13,7 @@ from repro.core.guided import GuidedConfig
 from repro.data import make_batch_for
 from repro.optim import constant, get_optimizer
 from repro.sharding.rules import LOCAL_CTX
-from repro.train import steps as S
+from repro.engine import mesh as M
 
 
 def _train(arch="yi_9b", mode="ssgd", guided=True, steps=8, opt_name="sgd",
@@ -24,8 +24,8 @@ def _train(arch="yi_9b", mode="ssgd", guided=True, steps=8, opt_name="sgd",
     if lr is None:
         # adaptive optimizers take ~unit-normalized steps: much smaller lr
         lr = 1e-2 if opt_name in ("sgd", "momentum") else 1e-3
-    params, _, gstate = S.make_train_state(jax.random.PRNGKey(seed), cfg, gcfg, opt, n_workers=4)
-    step = jax.jit(S.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(lr),
+    params, _, gstate = M.init_train_state(jax.random.PRNGKey(seed), cfg, gcfg, opt, n_workers=4)
+    step = jax.jit(M.build_train_step(cfg, gcfg, opt, LOCAL_CTX, constant(lr),
                                       n_micro=n_micro, n_workers=4))
     batch = {k: jnp.asarray(v) for k, v in make_batch_for(cfg, 32, 8, seed=seed).items()}
     losses = []
@@ -101,7 +101,7 @@ def test_guided_state_is_pytree_roundtrippable(tmp_path):
     cfg = get_config("xlstm_350m").reduced()
     gcfg = GuidedConfig(mode="dc_asgd")
     opt = get_optimizer("rmsprop")
-    params, _, gstate = S.make_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
+    params, _, gstate = M.init_train_state(jax.random.PRNGKey(0), cfg, gcfg, opt, n_workers=2)
     save(str(tmp_path), 0, {"params": params, "gstate": gstate})
     out = restore(str(tmp_path), 0, {"params": params, "gstate": gstate})
     n1 = jax.tree.leaves(out["gstate"])

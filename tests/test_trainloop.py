@@ -1,13 +1,14 @@
 """The chunked/prefetched mesh fit pipeline (repro.engine.trainloop, DESIGN.md §9).
 
-The headline contract: chunked multi-step dispatch (K train steps fused into
-one jitted lax.scan) + async double-buffered prefetch is BIT-EXACT with the
-per-step legacy loop — params, GuidedState and per-step history, leaf for
-leaf, for every registered strategy — while checkpoint cadence, bit-exact
-resume (including resume points between natural chunk boundaries), SIGTERM
-drain and the on_step contract all survive the regrouping. Plus the
-satellites: the chunk schedule, the prefetcher, the chunk-aware synthetic
-stream, and needs_correction skipping the second weighted forward+backward.
+The headline contract: every dispatch is one compiled loop over a chunk of K
+train steps, and any K (1 included) with or without the async double-buffered
+prefetch gives the same run BIT for bit — params, GuidedState and per-step
+history, leaf for leaf, for every registered strategy — while checkpoint
+cadence, bit-exact resume (including resume points between natural chunk
+boundaries), SIGTERM drain and the on_step contract all survive the
+regrouping. Plus the satellites: the chunk schedule, the prefetcher, the
+chunk-aware synthetic stream, and needs_correction skipping the second
+weighted forward+backward.
 """
 import os
 import signal
@@ -102,6 +103,38 @@ def test_chunked_matches_stepwise_bit_exact(strategy, mode):
     _assert_trees_equal(stepwise.state, chunked.state)
     assert stepwise.history == chunked.history  # per-step records, bit-equal
     assert chunked.n_steps == 6
+
+
+@pytest.mark.parametrize("strategy,mode", STRATEGIES)
+def test_chunk_of_one_matches_chunk_of_two_bit_exact(strategy, mode):
+    """A chunk of one is the same loop body as a longer chunk: fit(6) in six
+    dispatches of one step equals fit(6) in three of two, leaf for leaf."""
+    ones = Trainer.from_spec(_spec(strategy, mode)).fit()
+    twos = Trainer.from_spec(_spec(strategy, mode, chunk_steps=2)).fit()
+    _assert_trees_equal(ones.model, twos.model)
+    _assert_trees_equal(ones.state, twos.state)
+    assert ones.history == twos.history
+
+
+def test_chunk_of_one_compiles_to_a_loop():
+    """XLA inlines a loop it can count to one, and the inlined step rounds
+    differently; the dispatch built at chunk_steps=1 must keep its `while`."""
+    from repro.data import make_batch_for
+    from repro.engine import mesh as M
+    from repro.engine.trainloop import build_dispatch
+    from repro.optim import get_optimizer
+
+    spec = _spec()
+    cfg, ctx = spec.model_config(), M.build_ctx("local")
+    strat = Trainer.from_spec(spec).strategy
+    params, _, gstate = M.init_train_state(
+        jax.random.PRNGKey(0), cfg, spec.to_guided_config(),
+        get_optimizer(spec.optimizer), n_workers=2, strategy=strat)
+    block = {k: jnp.asarray(v)[None]
+             for k, v in make_batch_for(cfg, 8, 4, seed=0).items()}
+    dispatch = build_dispatch(spec, strat, cfg, ctx, 2, spec.steps)
+    text = dispatch.lower(params, gstate, block).compile().as_text()
+    assert " while(" in text
 
 
 def test_prefetch_is_bit_exact_chunked_and_stepwise():
@@ -218,14 +251,14 @@ def test_on_step_fires_per_chunk_with_stacked_metrics():
     assert seen == [(3, (4,)), (5, (2,))]
 
 
-def test_on_step_chunk_steps_1_keeps_legacy_scalar_contract():
+def test_on_step_chunk_steps_1_fires_per_step_with_stacked_metrics():
     seen = []
 
     def cb(step, m, params):
-        seen.append((step, tuple(getattr(m["loss"], "shape", ()))))
+        seen.append((step, tuple(m["loss"].shape)))
 
     Trainer.from_spec(_spec()).fit(on_step=cb)
-    assert seen == [(i, ()) for i in range(6)]  # per step, scalar metrics
+    assert seen == [(i, (1,)) for i in range(6)]  # per step, (1,) metrics
 
 
 def test_launcher_chunked_run_logs_per_step_history(capsys):
@@ -376,7 +409,7 @@ def test_batch_put_local_matches_asarray():
     from repro.data.prefetch import batch_put
     from repro.sharding.rules import LOCAL_CTX
 
-    put = batch_put(LOCAL_CTX, stacked=True)
+    put = batch_put(LOCAL_CTX)
     out = put({"tokens": np.arange(12).reshape(2, 3, 2)})
     assert isinstance(out["tokens"], jax.Array)
     np.testing.assert_array_equal(np.asarray(out["tokens"]),
