@@ -46,6 +46,12 @@ strategies without DC-ASGD's term), the kernel is launched without the
 `w_stale` operand and reads `w` in its place: `lam * g*g*(w - w)` is the
 same zero, and the update streams 6 B per bf16 parameter (sgd) instead of 8.
 A traced `lam` (the delay-simulation scan) keeps the stream.
+
+In place: each kernel aliases `w` and each accumulator to its new value
+(`input_output_aliases`), so inside a loop whose carry holds them (the train
+chunk's scan) the update writes over the carried buffers and XLA copies
+nothing aside before it. A caller that still reads the old `w` or state after
+the update pays XLA's copy of it, and gets the same result.
 """
 from __future__ import annotations
 
@@ -76,26 +82,35 @@ def _streams(kernel_fn, w, g, w_stale, lam):
     return kernel_fn, (w, g, w_stale)
 
 
-def _launch(kernel_fn, arrays, scalars, out_dtypes, block, interpret, name):
-    """One elementwise pallas_call over `arrays` (all of one leaf's shape),
-    each viewed and tiled as `repro.kernels.tiling` says, the scalar pack
-    riding along whole in SMEM as the last operand (Mosaic loads scalars only
-    from SMEM or VMEM; an ANY-space ref would need an explicit DMA). `name`
+def _launch(kernel_fn, lead, accs, scalars, block, interpret, name):
+    """One elementwise pallas_call over `lead` (`w`, `g`[, `w_stale`]) and the
+    accumulators `accs` (all of one leaf's shape), each viewed and tiled as
+    `repro.kernels.tiling` says, the scalar pack riding along whole in SMEM as
+    the last operand (Mosaic loads scalars only from SMEM or VMEM; an
+    ANY-space ref would need an explicit DMA). `w` and each accumulator are
+    aliased to their new values, so the update writes in place; the
+    accumulators are taken at the compute dtype they come back in. `name`
     names the custom call in the compiled program and in a profiler trace
-    (`guided_sgd_update.3`). Returns the outputs in the leaf's shape."""
+    (`guided_sgd_update.3`). Returns `(w', *accs')` in the leaf's shape."""
     if interpret is None:
         interpret = default_interpret()
+    w = lead[0]
+    ct = _compute_dtype(w.dtype)
+    arrays = (*lead, *(a.astype(ct) for a in accs))
+    out_dtypes = [w.dtype] + [ct] * len(accs)
     if block is None:
-        block = stream_block([a.dtype for a in arrays] + list(out_dtypes))
-    shape = arrays[0].shape
+        block = stream_block([a.dtype for a in arrays] + out_dtypes)
+    shape = w.shape
     view, block_shape, grid = tiling(shape, block)
     bspec = lambda: pl.BlockSpec(block_shape, lambda i, j, k: (i, j, k))
+    aliases = {0: 0, **{len(lead) + i: 1 + i for i in range(len(accs))}}
     outs = pl.pallas_call(
         kernel_fn,
         grid=grid,
         in_specs=[bspec() for _ in arrays] + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bspec() for _ in out_dtypes],
         out_shape=[jax.ShapeDtypeStruct(view, d) for d in out_dtypes],
+        input_output_aliases=aliases,
         interpret=interpret,
         name=name,
     )(*(a.reshape(view) for a in arrays), scalars)
@@ -181,7 +196,7 @@ def guided_sgd_update_raw(w, g, w_stale, lr, lam, *, block: int = None,
     ct = _compute_dtype(w.dtype)
     scalars = jnp.stack([jnp.asarray(lr, ct), jnp.asarray(lam, ct)])
     kernel_fn, lead = _streams(_sgd_kernel, w, g, w_stale, lam)
-    (out,) = _launch(kernel_fn, lead, scalars, [w.dtype], block, interpret,
+    (out,) = _launch(kernel_fn, lead, (), scalars, block, interpret,
                      "guided_sgd_update")
     return out
 
@@ -196,8 +211,8 @@ def guided_momentum_update_raw(w, g, w_stale, m, lr, lam, beta, *,
     ])
     kernel_fn, lead = _streams(partial(_momentum_kernel, nesterov), w, g,
                                w_stale, lam)
-    out, m_new = _launch(kernel_fn, (*lead, m), scalars, [w.dtype, ct], block,
-                         interpret, "guided_momentum_update")
+    out, m_new = _launch(kernel_fn, lead, (m,), scalars, block, interpret,
+                         "guided_momentum_update")
     return out, m_new
 
 
@@ -209,8 +224,8 @@ def guided_rmsprop_update_raw(w, g, w_stale, r, lr, lam, beta, eps, *,
         jnp.asarray(beta, ct), jnp.asarray(eps, ct),
     ])
     kernel_fn, lead = _streams(_rmsprop_kernel, w, g, w_stale, lam)
-    out, r_new = _launch(kernel_fn, (*lead, r), scalars, [w.dtype, ct], block,
-                         interpret, "guided_rmsprop_update")
+    out, r_new = _launch(kernel_fn, lead, (r,), scalars, block, interpret,
+                         "guided_rmsprop_update")
     return out, r_new
 
 
@@ -233,7 +248,6 @@ def guided_adam_update_raw(w, g, w_stale, m, v, t, lr, lam, b1, b2, eps, *,
         jnp.asarray(eps, ct),
     ])
     kernel_fn, lead = _streams(_adam_kernel, w, g, w_stale, lam)
-    out, m_new, v_new = _launch(kernel_fn, (*lead, m, v), scalars,
-                                [w.dtype, ct, ct], block, interpret,
-                                "guided_adam_update")
+    out, m_new, v_new = _launch(kernel_fn, lead, (m, v), scalars, block,
+                                interpret, "guided_adam_update")
     return out, m_new, v_new

@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode)."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -375,19 +377,22 @@ def test_fused_update_for_rejects_unfused_optimizer():
 # ------------------------------------------------- derived block, lam = 0 stream
 
 
-def _raw_update(name, lam):
-    """(fn(w, g, ws, *acc), n_acc) of one fused kernel at `lam`."""
+def _raw_update(name, lam, ref=False):
+    """(fn(w, g, ws, *acc), n_acc) of one fused kernel at `lam`; with `ref`,
+    of its pure-jnp reference."""
     from repro.kernels.guided_update import kernel as K
+    from repro.kernels.guided_update import ref as R
 
+    u = lambda base: getattr(R, f"{base}_ref") if ref else getattr(K, f"{base}_raw")
     if name == "sgd":
-        return (lambda w, g, ws: K.guided_sgd_update_raw(w, g, ws, 0.2, lam)), 0
+        return (lambda w, g, ws: u("guided_sgd_update")(w, g, ws, 0.2, lam)), 0
     if name == "momentum":
-        return (lambda w, g, ws, m: K.guided_momentum_update_raw(
+        return (lambda w, g, ws, m: u("guided_momentum_update")(
             w, g, ws, m, 0.2, lam, 0.9, nesterov=True)), 1
     if name == "rmsprop":
-        return (lambda w, g, ws, r: K.guided_rmsprop_update_raw(
+        return (lambda w, g, ws, r: u("guided_rmsprop_update")(
             w, g, ws, r, 0.2, lam, 0.9, 1e-8)), 1
-    return (lambda w, g, ws, m, v: K.guided_adam_update_raw(
+    return (lambda w, g, ws, m, v: u("guided_adam_update")(
         w, g, ws, m, v, 3, 0.2, lam, 0.9, 0.999, 1e-8)), 2
 
 
@@ -415,6 +420,73 @@ def test_lam_zero_drops_w_stale_stream_bit_identical(name):
     assert _pallas_inputs(traced, *args) == len(args) + 1
     for a, b in zip(jax.tree.leaves(static(*args)), jax.tree.leaves(traced(*args))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _tuple(out):
+    return out if isinstance(out, tuple) else (out,)
+
+
+def _assert_same_bits(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("caller", ["reads_old", "donated_scan"])
+@pytest.mark.parametrize("name,lam", [("sgd", 0.0), ("sgd", 0.04), ("momentum", 0.04),
+                                      ("rmsprop", 0.04), ("adam", 0.04)])
+def test_in_place_update_is_bit_identical(monkeypatch, name, lam, caller):
+    """Each kernel writes `w` and its accumulators in place over donated
+    buffers, and gives the bits of the reference and of the same kernel
+    launched with fresh outputs: where the caller still reads the old values
+    after the update in the same jit (XLA copies them aside), and over a
+    three-step scan. The rmsprop kernel rounds 1 - beta from its float32
+    beta where the reference rounds the Python float (2.4e-7 apart at beta =
+    0.9), so its r' is the reference's to that, with or without the aliases."""
+    rng = np.random.default_rng(18)
+    shape = (3, 40, 2, 136)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    fn, n_acc = _raw_update(name, lam)
+    ref, _ = _raw_update(name, lam, ref=True)
+    w = normal(*shape).astype(jnp.bfloat16)
+    g = (0.01 * normal(3, *shape)).astype(jnp.bfloat16)
+    ws = (w + 0.05).astype(jnp.bfloat16)
+    acc = tuple(0.1 * jnp.abs(normal(*shape)) for _ in range(n_acc))
+
+    if caller == "reads_old":
+        def run(f):
+            @partial(jax.jit, donate_argnums=(0, 3))
+            def step(w, g, ws, acc):
+                new = _tuple(f(w, g, ws, *acc))
+                return new, tuple(n.astype(jnp.float32) - o for n, o in zip(new, (w, *acc)))
+
+            return step(jnp.copy(w), g[0], ws, tuple(jnp.copy(a) for a in acc))
+    else:
+        def run(f):
+            @partial(jax.jit, donate_argnums=(0, 3))
+            def scan(w, gs, ws, acc):
+                def body(carry, g):
+                    out = _tuple(f(carry[0], g, ws, *carry[1]))
+                    return (out[0], out[1:]), None
+
+                return jax.lax.scan(body, (w, acc), gs)[0]
+
+            return scan(jnp.copy(w), g, ws, tuple(jnp.copy(a) for a in acc))
+
+    in_place, want = run(fn), run(ref)
+    if name == "rmsprop":
+        got_w, got_r = jax.tree.leaves(in_place)[:2]
+        want_w, want_r = jax.tree.leaves(want)[:2]
+        np.testing.assert_array_equal(np.asarray(got_w), np.asarray(want_w))
+        np.testing.assert_allclose(np.asarray(got_r), np.asarray(want_r), rtol=1e-6, atol=0)
+    else:
+        _assert_same_bits(in_place, want)
+    from jax.experimental import pallas as pl
+
+    call = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call", lambda *a, input_output_aliases=None, **kw:
+                        call(*a, **kw))
+    _assert_same_bits(in_place, run(fn))
 
 
 @pytest.mark.parametrize("shape", [(1000,), (37, 129), (3, 40, 2, 136), (5, 3, 700)],

@@ -75,11 +75,14 @@ def _update(name, block, lam=0.04):
 
 
 def _compile(name, shape, dtype, block, sharding, lam=0.04):
+    """The kernel jitted with `w` and its accumulators donated, as the train
+    step's carry donates them: each is updated in its own buffer."""
     fn, n_acc = _update(name, block, lam)
     acc = jnp.promote_types(dtype, jnp.float32)
     args = ([jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * 3
             + [jax.ShapeDtypeStruct(shape, acc, sharding=sharding)] * n_acc)
-    return jax.jit(fn).lower(*args).compile()
+    donate = (0, *range(3, 3 + n_acc))
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
 
 def _kernel_lines(compiled):
@@ -196,6 +199,105 @@ def test_fused_update_compiles_per_shard_on_2x2_mesh(topo, monkeypatch):
     text = jax.jit(apply).lower(p, p, p, {}, lr).compile().as_text()
     assert text.count("tpu_custom_call") == len(jax.tree.leaves(params))
     assert "all-gather" not in text  # each kernel reads only its own shard
+
+
+#: the benchmark's training cells: yi-9b's gSSGD (sgd at lambda = 0) and
+#: minicpm-2b's DC-ASGD (adam at lambda = 0.04)
+CELLS = ["yi-9b.gssgd-c4", "minicpm-2b.dcasgd-adam-c4"]
+_BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "chip")
+
+
+def _cell_step(cell, sharding):
+    """The dispatch `fit` builds for a benchmark cell (a chunk of its train
+    steps, the carry donated), compiled on the train state's shapes. The
+    benchmark's directory must be on `sys.path`."""
+    import json
+
+    import training
+
+    from repro.engine import mesh as M
+    from repro.engine import trainloop
+    from repro.optim import get_optimizer
+
+    with open(os.path.join(_BENCH, "..", "..", "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (wl,) = [w for w in bench["workloads"] if w["name"] == cell]
+    (cf,) = [c for c in bench["configs"] if c["name"] == wl["config"]]
+    with open(os.path.join(_BENCH, "..", "..", cf["file"])) as f:
+        cfg_file = json.load(f)
+    with open(os.path.join(_BENCH, "traffic", wl["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    spec = training.make_spec(cfg_file, traffic, 0)
+    cfg, gcfg = spec.model_config(), spec.to_guided_config()
+    strategy = M.resolve_strategy(gcfg, spec.strategy)
+    state = jax.eval_shape(lambda: M.init_train_state(
+        jax.random.PRNGKey(0), cfg, gcfg, get_optimizer(spec.optimizer),
+        spec.workers, strategy)[::2])
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+                         state)
+    tok = jax.ShapeDtypeStruct((spec.chunk_steps, spec.global_batch, spec.seq_len), "int32",
+                               sharding=sharding)
+    dispatch = trainloop.build_dispatch(spec, strategy, cfg, M.build_ctx(spec.mesh),
+                                        spec.workers, 100)
+    return dispatch.lower(*state, {"tokens": tok, "labels": tok}).compile()
+
+
+def _in_place_operand_copies(text):
+    """The copies of more than 1 MiB that feed (through bitcasts) an operand
+    an update kernel writes in place: its first, `w`, and its last arrays
+    before the scalar pack, one per accumulator (one per result past `w`)."""
+    itemsize = {"bf16": 2, "f32": 4}
+    defs, kernels = {}, []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$", ln)
+        if not m:
+            continue
+        name, typ, op, rest = m.groups()
+        defs[name] = (typ, op, re.findall(r"%([\w.\-]+)", rest.split("), ")[0]))
+        if op == "custom-call" and re.match(r"guided_\w+_update", name):
+            kernels.append(name)
+    assert kernels
+    copies = []
+    for k in kernels:
+        typ, _, operands = defs[k]
+        n_acc = len(re.findall(r"\w+\[[\d,]*\]", typ)) - 1
+        arrays = operands[:-1]
+        for a in [arrays[0], *arrays[len(arrays) - n_acc:]]:
+            while defs[a][1] == "bitcast":
+                (a,) = defs[a][2]
+            t = re.match(r"(\w+)\[([\d,]*)\]", defs[a][0])
+            size = itemsize.get(t.group(1), 4) * int(np.prod([int(d) for d in t.group(2).split(",")]))
+            if defs[a][1] == "copy" and size > 2 ** 20:
+                copies.append((k, a, size))
+    return copies
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_step_updates_parameters_and_state_in_place(topo, one_chip, monkeypatch, cell):
+    """In a cell's step compiled for a v5e, no copy of more than 1 MiB feeds
+    `w` or an accumulator of an update kernel: each kernel writes them in
+    place, in the loop-carried buffers of the chunk's scan. Launched with
+    fresh outputs (the kernels' aliases dropped) the same step copies each of
+    them aside before its kernel, and needs more scratch."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.guided_update import ops as O
+    from repro.models import layers as L
+
+    # the host is a CPU: steer the step onto the chip's kernels
+    monkeypatch.setattr(K, "default_interpret", lambda: False)
+    monkeypatch.setattr(O, "default_interpret", lambda: False)
+    monkeypatch.setattr(L, "_backend", lambda: "tpu")
+    monkeypatch.syspath_prepend(_BENCH)
+    in_place = _cell_step(cell, one_chip)
+    assert _in_place_operand_copies(in_place.as_text()) == []
+    call = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call", lambda *a, input_output_aliases=None, **kw:
+                        call(*a, **kw))
+    fresh = _cell_step(cell, one_chip)
+    assert _in_place_operand_copies(fresh.as_text())
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes
+    assert temp(in_place) <= temp(fresh)
 
 
 #: one layer's causal attention in a training cell: (B, S, H, K, d_head) of
